@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.aggregation import chain_weights
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run() -> list[tuple[int, float, float, float]]:
@@ -55,6 +56,7 @@ def run() -> list[tuple[int, float, float, float]]:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     print("n_params,chain_us,fused_us,speedup")
     for p, c, f, s in run():
         print(f"{p},{c:.0f},{f:.0f},{s:.2f}")

@@ -15,6 +15,7 @@ import dataclasses
 import json
 
 from repro.sim import SatcomSimulator, SimConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _curves(panel: str, quick: bool) -> dict[str, SimConfig]:
@@ -93,6 +94,7 @@ if __name__ == "__main__":
     ap.add_argument("--rounds", type=int, default=25)
     ap.add_argument("--out")
     args = ap.parse_args()
+    use_compile_cache()
     if args.sim_wallclock:
         res = sim_wallclock(quick=not args.full, rounds=args.rounds)
         if args.out:

@@ -28,14 +28,13 @@ Four sections, all recorded to ``BENCH_sim.json`` (schema documented in
   fedhap_async, and fedhap_buffered on the paper 5x8 shell and a 10x20
   shell: K planned rounds (or cycle events) batched into schedule
   tensors and executed as one device dispatch.
-- **sim_sharded** — 1-vs-8 forced-host-device scaling of the sharded
-  fused megastep (``SimConfig.data_shards`` -> shard_map over the
-  satellite axis, aggregation through the production mesh round's
-  weighted psum): fedhap on a ``grid:3x6`` gateway grid over a 20x40
-  shell and a two-shell ``shells:`` constellation, each (scenario,
-  device count) in its own subprocess (device count is fixed at first
-  jax init). Real local SGD included — sharding accelerates the
-  train+fold megastep itself.
+- **sim_sharded** — 1-vs-D device scaling of the sharded fused
+  megastep (``SimConfig.data_shards`` -> shard_map over the satellite
+  axis, aggregation through the production mesh round's weighted
+  psum): fedhap on a ``grid:3x6`` gateway grid over a 20x40 shell and
+  a two-shell ``shells:`` constellation, over every device of this one
+  process (skipped with one device). Real local SGD included —
+  sharding accelerates the train+fold megastep itself.
 - **sweep** — ``haps:N`` / ``grid:RxC`` station scenarios crossed with
   large Walker shells: records grid-build time and scheduler-only
   FedHAP rounds/sec (local SGD excluded, as in ``sim_wallclock``).
@@ -65,6 +64,7 @@ from repro.orbits.routing import (
     earliest_arrival,
     earliest_arrival_reference,
 )
+from repro.launch.compile_cache import use_compile_cache
 from repro.sim import SimConfig
 from repro.sim.engine import RoundEngine, _make_stations
 
@@ -467,28 +467,20 @@ def bench_sim_fused(smoke: bool) -> list[dict]:
     return out
 
 
-def _sharded_worker(spec_json: str) -> None:
-    """Measure fused fedhap rounds/s for one (scenario, device count)
-    in THIS process and print a ``SHARDED_RESULT`` JSON line.
-
-    Runs as a subprocess of :func:`bench_sim_sharded` because the XLA
-    device count is fixed at first jax init: the parent sets
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=D`` in the
-    worker's environment before spawn. The first ``run()`` pays
-    compilation; the second measures steady-state throughput (real
-    local SGD included — sharding accelerates the train+fold megastep
-    itself, unlike the scheduling-only sections)."""
-    import jax
-
-    spec = json.loads(spec_json)
-    cfg = SimConfig(strategy="fedhap", stations=spec["stations"],
-                    num_orbits=spec.get("num_orbits", 5),
-                    sats_per_orbit=spec.get("sats_per_orbit", 8),
-                    shells=spec.get("shells", ""),
-                    data_shards=spec["data_shards"],
-                    local_steps=spec["local_steps"],
-                    horizon_h=spec["horizon_h"], time_step_s=60.0,
-                    max_rounds=spec["rounds"], target_accuracy=2.0,
+def _sharded_sample(sc: dict, data_shards: int) -> dict:
+    """Fused fedhap rounds/s for one scenario at one shard count in
+    this process: the first ``run()`` pays compilation, the second
+    measures steady-state throughput (real local SGD included —
+    sharding accelerates the train+fold megastep itself, unlike the
+    scheduling-only sections)."""
+    cfg = SimConfig(strategy="fedhap", stations=sc["stations"],
+                    num_orbits=sc.get("num_orbits", 5),
+                    sats_per_orbit=sc.get("sats_per_orbit", 8),
+                    shells=sc.get("shells", ""),
+                    data_shards=data_shards,
+                    local_steps=sc["local_steps"],
+                    horizon_h=sc["horizon_h"], time_step_s=60.0,
+                    max_rounds=sc["rounds"], target_accuracy=2.0,
                     **_SIM_LITE)
     eng = RoundEngine(cfg)
     t0 = time.perf_counter()
@@ -497,28 +489,27 @@ def _sharded_worker(spec_json: str) -> None:
     t0 = time.perf_counter()
     res = eng.run()
     dt = time.perf_counter() - t0
-    assert res.rounds == spec["rounds"], \
-        f"horizon exhausted: {res.rounds}/{spec['rounds']} rounds"
-    print("SHARDED_RESULT " + json.dumps({
-        "devices": jax.device_count(), "rounds": res.rounds,
-        "compile_s": round(compile_s, 2), "steady_s": round(dt, 3),
-        "rps": round(res.rounds / dt, 3)}), flush=True)
+    assert res.rounds == sc["rounds"], \
+        f"horizon exhausted: {res.rounds}/{sc['rounds']} rounds"
+    return {"rounds": res.rounds, "compile_s": round(compile_s, 2),
+            "rps": round(res.rounds / dt, 3)}
 
 
-def bench_sim_sharded(smoke: bool, devices: int = 8) -> list[dict]:
-    """1-vs-``devices`` forced-host-device scaling of the sharded fused
-    megastep (``SimConfig.data_shards`` -> shard_map over the satellite
-    axis): fedhap on a dense gateway grid, single-shell and two-shell.
-    Each (scenario, device count) runs in its own subprocess
-    (:func:`_sharded_worker`) so every sample gets a fresh XLA device
-    pool. On one physical CPU the forced devices share cores, so
-    ``scaling`` measures dispatch/collective overhead rather than true
-    speedup — the accelerator-relevant number is that it stays near
-    wall-parity while exercising the production psum path."""
-    import os
-    import subprocess
-    import sys
+def bench_sim_sharded(smoke: bool) -> list[dict]:
+    """1-vs-D scaling of the sharded fused megastep
+    (``SimConfig.data_shards`` -> shard_map over the satellite axis):
+    fedhap on a dense gateway grid, single-shell and two-shell. D is
+    this process's device count, and one process drives every device:
+    CI's multidevice tier forces 8 host devices through ``XLA_FLAGS``,
+    a TPU host brings its chips. On one physical CPU the forced
+    devices share cores, so ``scaling`` measures dispatch/collective
+    overhead rather than true speedup. Skipped with one device."""
+    import jax
 
+    devices = jax.device_count()
+    if devices < 2:
+        print("  sim_sharded: skipped (one device)", flush=True)
+        return []
     if smoke:
         scenarios = [
             dict(stations="grid:3x6", num_orbits=6, sats_per_orbit=10,
@@ -541,24 +532,8 @@ def bench_sim_sharded(smoke: bool, devices: int = 8) -> list[dict]:
             f"{sc['num_orbits']}x{sc['sats_per_orbit']}"
         row: dict = {"scenario": f"{sc['stations']} x {label}",
                      "devices": devices}
-        for d in (1, devices):
-            spec = dict(sc, data_shards=0 if d == 1 else d)
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = \
-                f"--xla_force_host_platform_device_count={d}"
-            proc = subprocess.run(
-                [sys.executable, "-m", "benchmarks.bench_geometry",
-                 "--sharded-worker", json.dumps(spec)],
-                capture_output=True, text=True, env=env, timeout=3600)
-            if proc.returncode:
-                raise RuntimeError(
-                    f"sharded worker failed (D={d}):\n{proc.stdout}\n"
-                    f"{proc.stderr}")
-            line = [ln for ln in proc.stdout.splitlines()
-                    if ln.startswith("SHARDED_RESULT ")][-1]
-            res = json.loads(line.split(" ", 1)[1])
-            assert res["devices"] == d, (res["devices"], d)
-            tag = "1" if d == 1 else "sharded"
+        for tag, shards in (("1", 0), ("sharded", devices)):
+            res = _sharded_sample(sc, shards)
             row[f"rps_{tag}"] = res["rps"]
             row[f"compile_s_{tag}"] = res["compile_s"]
             row["rounds"] = res["rounds"]
@@ -820,11 +795,8 @@ def main() -> None:
                          "rounds/sec comparison")
     ap.add_argument("--rounds", type=int, default=25)
     ap.add_argument("--sharded-only", action="store_true",
-                    help="run only the sim_sharded 1-vs-8 device "
+                    help="run only the sim_sharded 1-vs-D device "
                          "scaling section (the CI multi-device tier)")
-    ap.add_argument("--sharded-worker", metavar="SPEC_JSON",
-                    help="internal: measure one (scenario, device "
-                         "count) sample in this process")
     ap.add_argument("--faults-only", action="store_true",
                     help="run only the fault-plane overhead + "
                          "accuracy-vs-outage section (the CI chaos "
@@ -832,9 +804,7 @@ def main() -> None:
     ap.add_argument("--out", default=str(DEFAULT_OUT),
                     help="where to write BENCH_sim.json")
     args = ap.parse_args()
-    if args.sharded_worker:
-        _sharded_worker(args.sharded_worker)
-        return
+    use_compile_cache()
     if args.sharded_only:
         doc = {"schema": 1, "smoke": args.smoke,
                "sim_sharded": bench_sim_sharded(args.smoke)}

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _time(f, *args, iters=5):
@@ -72,5 +73,6 @@ def run() -> list[tuple[str, float, float]]:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     for name, us, err in run():
         print(f"{name},{us:.1f},{err:.2e}")

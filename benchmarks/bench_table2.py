@@ -11,6 +11,7 @@ import json
 import time
 
 from repro.core.strategies import TABLE2_SETUPS
+from repro.launch.compile_cache import use_compile_cache
 from repro.sim import SatcomSimulator, SimConfig
 import dataclasses
 
@@ -79,6 +80,7 @@ if __name__ == "__main__":
     ap.add_argument("--rounds", type=int, default=25)
     ap.add_argument("--out")
     args = ap.parse_args()
+    use_compile_cache()
     if args.sim_wallclock:
         res = sim_wallclock(rounds=args.rounds)
         if args.out:

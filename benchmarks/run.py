@@ -9,6 +9,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -17,6 +19,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma list: kernels,agg,table2,fig3,roofline")
     args = ap.parse_args()
+    use_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     def want(name):

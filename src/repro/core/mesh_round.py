@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.dissemination import (
     ConstellationMeshMap,
     hap_chain_down,
@@ -301,7 +300,7 @@ def build_round(
     if kind == "fedavg":
         stats_spec = {"gate": P(), "covered": P(), "upload_mass": P()}
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(pspecs, scalar_spec, scalar_spec),

@@ -26,7 +26,7 @@ def _on_cpu() -> bool:
 
 @functools.partial(jax.jit, static_argnames=("block_p",))
 def fedagg_op(stacked: jax.Array, weights: jax.Array,
-              block_p: int = 16_384) -> jax.Array:
+              block_p: int | None = None) -> jax.Array:
     return fedagg(stacked, weights, block_p=block_p, interpret=_on_cpu())
 
 

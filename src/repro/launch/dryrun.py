@@ -32,7 +32,6 @@ import traceback
 
 import jax
 
-from repro.compat import set_mesh
 import jax.numpy as jnp
 
 from repro.configs import SHAPES, get_config, list_configs
@@ -119,7 +118,7 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.perf_counter()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.mode == "train":
             step, params_sh, shardings_for, cmap = make_train_step(
                 model, mesh, round_kind=round_kind,
@@ -165,8 +164,6 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool,
 
     mem = _memory_dict(compiled)
     cost_raw = compiled.cost_analysis() or {}
-    if isinstance(cost_raw, (list, tuple)):   # old jax: one dict per device
-        cost_raw = cost_raw[0] if cost_raw else {}
     cost = {k: float(v) for k, v in cost_raw.items()
             if isinstance(v, (int, float)) and k in
             ("flops", "bytes accessed", "transcendentals",

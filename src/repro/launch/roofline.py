@@ -32,7 +32,6 @@ import pathlib
 
 import jax
 
-from repro.compat import set_mesh
 import jax.numpy as jnp
 
 from repro.configs import SHAPES, get_config, list_configs
@@ -70,8 +69,6 @@ _SUGGEST = {
 
 def _extract(compiled) -> dict:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):       # old jax: per-device dicts
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = parse_collective_bytes(hlo)
     return {
@@ -220,7 +217,7 @@ def roofline_one(arch: str, shape_name: str, multi_pod: bool = False,
     chips = int(jax.device_count())
     n_periods = cfg.num_layers // len(cfg.block_pattern)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c1 = _extract(_lower_compute(_variant(cfg, 1), shape, mesh, cmap))
         c2 = _extract(_lower_compute(_variant(cfg, 2), shape, mesh, cmap))
         per_period = {k: c2[k] - c1[k] for k in ("flops", "bytes",

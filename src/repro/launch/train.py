@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import save_checkpoint
-from repro.compat import set_mesh
 from repro.configs import get_config, list_configs
 from repro.core.dissemination import ConstellationMeshMap
 from repro.core.weights import mu_weights
@@ -30,6 +29,7 @@ from repro.core.fed_step import (
 )
 from repro.core.mesh_round import FedRoundConfig
 from repro.data.tokens import TokenTaskConfig, make_token_dataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.transformer import Transformer
 
 
@@ -68,6 +68,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -103,7 +104,7 @@ def main() -> None:
     rng = np.random.default_rng(args.seed)
 
     if mesh.shape["data"] == n_sats:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step_fn = jax.jit(build_fed_train_step(model, fed_cfg, mesh))
     else:
         step_fn = jax.jit(_single_device_round(model, fed_cfg))
@@ -111,7 +112,7 @@ def main() -> None:
     print(f"[train] {cfg.name}: {model.count_params()/1e6:.1f}M params, "
           f"{n_sats} satellites, {args.round_kind}")
     t0 = time.perf_counter()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for rnd in range(args.rounds):
             batch = make_batches(cfg, n_sats, args.batch_per_sat, args.seq,
                                  rnd, cfg.vocab_size)

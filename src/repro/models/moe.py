@@ -86,7 +86,7 @@ def _moe_tokens(cfg: ArchConfig, p: dict, xt: jax.Array
 
     # ---- flatten assignments and sort by expert (stable).
     e_flat = gate_idx.reshape(-1)                          # (T*k,)
-    t_flat = jnp.repeat(jnp.arange(t), k)                  # token of each slot
+    t_flat = jnp.arange(t * k) // k                        # token of each slot
     g_flat = gate_vals.reshape(-1)
     order = jnp.argsort(e_flat, stable=True)
     e_sorted = e_flat[order]

@@ -285,6 +285,7 @@ class SimResult:
     final_accuracy: float
     rounds: int
     sim_hours: float
+    params: Any = None        # final global model (device-resident)
 
     def time_to_accuracy(self, acc: float) -> Optional[float]:
         for t, _, a in self.history:
@@ -1204,7 +1205,8 @@ class RoundEngine:
                     self.ckpt_tick(s, {"params": s.params})
         finally:
             self._ckpt = None
-        return SimResult(s.history, s.acc, len(s.history), s.t / 3600.0)
+        return SimResult(s.history, s.acc, len(s.history), s.t / 3600.0,
+                         s.params)
 
 
 # The engine is API-compatible with the pre-registry monolith.

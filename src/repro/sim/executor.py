@@ -50,6 +50,7 @@ construction.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -57,7 +58,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.mesh_round import sharded_fold
 from repro.core.treeops import (
     tree_broadcast,
@@ -97,8 +97,6 @@ class FusedExecutor:
                  eval_labels: np.ndarray, *, eval_chunk: int = 1024,
                  use_pallas: Optional[bool] = None, mesh: Any = None):
         self.trainer = trainer
-        self._x = jnp.asarray(fd.images)
-        self._y = jnp.asarray(np.asarray(fd.labels, np.int32))
         self.use_pallas = use_pallas
         self.mesh = mesh
         if mesh is not None and "data" not in mesh.axis_names:
@@ -121,8 +119,18 @@ class FusedExecutor:
             ex = np.concatenate(
                 [ex, np.zeros((pad,) + ex.shape[1:], ex.dtype)])
             ey = np.concatenate([ey, np.full(pad, -1, ey.dtype)])
-        self._ex = jnp.asarray(ex.reshape(-1, c, *ex.shape[1:]))
-        self._ey = jnp.asarray(ey.reshape(-1, c))
+        # Training set + chunked eval set, handed to every program as an
+        # argument: a closed-over array would be baked into the
+        # executable as a constant (a ~250 MB program for the paper
+        # dataset, too large for a persistent compile cache). The mesh
+        # programs take a replicated copy; the tick programs, which run
+        # on one device, the local one.
+        self._data_local = (
+            jnp.asarray(fd.images),
+            jnp.asarray(np.asarray(fd.labels, np.int32)),
+            jnp.asarray(ex.reshape(-1, c, *ex.shape[1:])),
+            jnp.asarray(ey.reshape(-1, c)))
+        self._data = self._replicate(self._data_local)
 
     # ------------------------------------------------------------ basics
     def _fold(self, stacked: Any, weights: Any) -> Any:
@@ -153,33 +161,40 @@ class FusedExecutor:
                 out[name] = np.pad(a, width)   # zero rows / zero weights
         return out
 
-    def _device_acc(self, params: Any) -> jax.Array:
+    def _device_acc(self, data: tuple, params: Any) -> jax.Array:
         """Fraction of the eval set classified correctly — the chunked
         accuracy reduction run inside the megastep (single f32 scalar;
         no host transfer until the block boundary)."""
         if self._eval_n == 0:
             return jnp.float32(0.0)
         model = self.trainer.model
+        ex, ey = data[2], data[3]
 
         def chunk_correct(xy):
             x, y = xy
             pred = jnp.argmax(model.forward(params, x), axis=-1)
             return jnp.sum((pred == y).astype(jnp.float32))
 
-        correct = jnp.sum(jax.lax.map(chunk_correct, (self._ex, self._ey)))
+        correct = jnp.sum(jax.lax.map(chunk_correct, (ex, ey)))
         return correct / jnp.float32(self._eval_n)
 
     def _nan_acc(self, params: Any) -> jax.Array:
         return jnp.full((), jnp.nan, jnp.float32)
 
-    def _train(self, base: Any, idx: jax.Array, n_rep: int,
+    def _batches(self, data: tuple, idx: jax.Array, n_rep: int,
+                 n_steps: int):
+        """Device gather of ``n_rep`` replicas' sampled mini-batches."""
+        bs = self.trainer.batch_size
+        x_all, y_all = data[0], data[1]
+        x = x_all[idx].reshape(n_rep, n_steps, bs, *x_all.shape[1:])
+        return x, y_all[idx].reshape(n_rep, n_steps, bs)
+
+    def _train(self, data: tuple, base: Any, idx: jax.Array, n_rep: int,
                n_steps: int) -> Any:
         """The megastep's train half: device gather of the sampled
         mini-batch indices + one vmapped SGD burst over ``n_rep``
         replicas broadcast from ``base`` inside jit."""
-        bs = self.trainer.batch_size
-        x = self._x[idx].reshape(n_rep, n_steps, bs, *self._x.shape[1:])
-        y = self._y[idx].reshape(n_rep, n_steps, bs)
+        x, y = self._batches(data, idx, n_rep, n_steps)
         trained, _ = jax.vmap(self.trainer.multi_step)(
             tree_broadcast(base, n_rep), x, y)
         return trained
@@ -237,17 +252,18 @@ class FusedExecutor:
         key = ("round", K, S, n_steps)
         fn = self._jit.get(key)
         if fn is None:
-            def block(params, idx, mu, do_eval, valid):
+            def block(params, data, idx, mu, do_eval, valid):
                 def body(p, inp):
                     idx_r, mu_r, ev, va = inp
 
                     def megastep(p):
-                        trained = self._train(p, idx_r, S, n_steps)
+                        trained = self._train(data, p, idx_r, S, n_steps)
                         return self._fold(trained, mu_r)
 
                     p = jax.lax.cond(va, megastep, lambda q: q, p)
-                    acc = jax.lax.cond(ev & va, self._device_acc,
-                                       self._nan_acc, p)
+                    acc = jax.lax.cond(
+                        ev & va, functools.partial(self._device_acc, data),
+                        self._nan_acc, p)
                     return p, acc
 
                 return jax.lax.scan(body, params,
@@ -255,7 +271,7 @@ class FusedExecutor:
 
             fn = jax.jit(block, donate_argnums=0)
             self._jit[key] = fn
-        params, accs = fn(params, _h2d(idx, np.int32),
+        params, accs = fn(params, self._data, _h2d(idx, np.int32),
                           _h2d(mu, np.float32),
                           jnp.asarray(do_eval), jnp.asarray(valid))
         return params, np.asarray(accs)
@@ -286,31 +302,33 @@ class FusedExecutor:
         key = ("round_sharded", K, Sp, n_steps)
         fn = self._jit.get(key)
         if fn is None:
-            def block(params, idx, mu, do_eval, valid):
+            def block(params, data, idx, mu, do_eval, valid):
                 def body(p, inp):
                     idx_r, mu_r, ev, va = inp
 
                     def megastep(p):
-                        trained = self._train(p, idx_r, s_loc, n_steps)
+                        trained = self._train(data, p, idx_r, s_loc,
+                                              n_steps)
                         return sharded_fold(trained, mu_r, ("data",),
                                             self.use_pallas)
 
                     p = jax.lax.cond(va, megastep, lambda q: q, p)
-                    acc = jax.lax.cond(ev & va, self._device_acc,
-                                       self._nan_acc, p)
+                    acc = jax.lax.cond(
+                        ev & va, functools.partial(self._device_acc, data),
+                        self._nan_acc, p)
                     return p, acc
 
                 return jax.lax.scan(body, params,
                                     (idx, mu, do_eval, valid))
 
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 block, mesh=self.mesh,
-                in_specs=(P(), P(None, "data", None), P(None, "data"),
-                          P(), P()),
-                out_specs=(P(), P()))
+                in_specs=(P(), P(), P(None, "data", None),
+                          P(None, "data"), P(), P()),
+                out_specs=(P(), P()), check_vma=False)
             fn = jax.jit(sharded, donate_argnums=0)
             self._jit[key] = fn
-        params, accs = fn(self._replicate(params),
+        params, accs = fn(self._replicate(params), self._data,
                           _h2d(idx, np.int32),
                           _h2d(mu, np.float32),
                           jnp.asarray(do_eval), jnp.asarray(valid))
@@ -356,8 +374,8 @@ class FusedExecutor:
         key = ("cycle", K, k, B, n_steps)
         fn = self._jit.get(key)
         if fn is None:
-            def block(params, bases, buf, l, idx, lam, rhos, keep, slot,
-                      flush, do_eval, valid):
+            def block(params, bases, buf, data, l, idx, lam, rhos, keep,
+                      slot, flush, do_eval, valid):
                 def body(carry, inp):
                     g, bases, buf = carry
                     (l_e, idx_e, lam_e, rhos_e, keep_e, slot_e, fl, evf,
@@ -366,7 +384,8 @@ class FusedExecutor:
                     def event(args):
                         g, bases, buf = args
                         base = tree_row(bases, l_e)
-                        trained = self._train(base, idx_e, k, n_steps)
+                        trained = self._train(data, base, idx_e, k,
+                                              n_steps)
                         orbit_model = self._fold(trained, lam_e)
                         buf = tree_set_row(buf, slot_e, orbit_model)
 
@@ -382,8 +401,9 @@ class FusedExecutor:
 
                     g, bases, buf = jax.lax.cond(
                         va, event, lambda a: a, (g, bases, buf))
-                    acc = jax.lax.cond(evf & va, self._device_acc,
-                                       self._nan_acc, g)
+                    acc = jax.lax.cond(
+                        evf & va, functools.partial(self._device_acc, data),
+                        self._nan_acc, g)
                     return (g, bases, buf), acc
 
                 (g, bases, buf), accs = jax.lax.scan(
@@ -395,7 +415,7 @@ class FusedExecutor:
             fn = jax.jit(block, donate_argnums=(0, 1, 2))
             self._jit[key] = fn
         g, bases, buf, accs = fn(
-            params, bases, buf,
+            params, bases, buf, self._data,
             _h2d(ev["l"], np.int32),
             _h2d(ev["idx"], np.int32),
             _h2d(ev["lam"], np.float32),
@@ -428,8 +448,8 @@ class FusedExecutor:
         key = ("cycle_sharded", K, kp, B, n_steps)
         fn = self._jit.get(key)
         if fn is None:
-            def block(params, bases, buf, l, idx, lam, rhos, keep, slot,
-                      flush, do_eval, valid):
+            def block(params, bases, buf, data, l, idx, lam, rhos, keep,
+                      slot, flush, do_eval, valid):
                 def body(carry, inp):
                     g, bases, buf = carry
                     (l_e, idx_e, lam_e, rhos_e, keep_e, slot_e, fl, evf,
@@ -438,7 +458,7 @@ class FusedExecutor:
                     def event(args):
                         g, bases, buf = args
                         base = tree_row(bases, l_e)
-                        trained = self._train(base, idx_e, k_loc,
+                        trained = self._train(data, base, idx_e, k_loc,
                                               n_steps)
                         orbit_model = sharded_fold(
                             trained, lam_e, ("data",), self.use_pallas)
@@ -456,8 +476,9 @@ class FusedExecutor:
 
                     g, bases, buf = jax.lax.cond(
                         va, event, lambda a: a, (g, bases, buf))
-                    acc = jax.lax.cond(evf & va, self._device_acc,
-                                       self._nan_acc, g)
+                    acc = jax.lax.cond(
+                        evf & va, functools.partial(self._device_acc, data),
+                        self._nan_acc, g)
                     return (g, bases, buf), acc
 
                 (g, bases, buf), accs = jax.lax.scan(
@@ -466,17 +487,17 @@ class FusedExecutor:
                      valid))
                 return g, bases, buf, accs
 
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 block, mesh=self.mesh,
-                in_specs=(P(), P(), P(), P(), P(None, "data", None),
+                in_specs=(P(), P(), P(), P(), P(), P(None, "data", None),
                           P(None, "data"), P(), P(), P(), P(), P(),
                           P()),
-                out_specs=(P(), P(), P(), P()))
+                out_specs=(P(), P(), P(), P()), check_vma=False)
             fn = jax.jit(sharded, donate_argnums=(0, 1, 2))
             self._jit[key] = fn
         g, bases, buf, accs = fn(
             self._replicate(params), self._replicate(bases),
-            self._replicate(buf),
+            self._replicate(buf), self._data,
             _h2d(ev["l"], np.int32),
             _h2d(ev["idx"], np.int32),
             _h2d(ev["lam"], np.float32),
@@ -578,15 +599,12 @@ class FusedExecutor:
         key = ("fedsat", Vp, k, n_steps)
         fn = self._jit.get(key)
         if fn is None:
-            def event(params, bases, visited, idx, lam_rows, rhos,
+            def event(params, bases, data, visited, idx, lam_rows, rhos,
                       valid):
                 base_rows = jax.tree.map(lambda b: b[visited], bases)
                 rep = jax.tree.map(
                     lambda b: jnp.repeat(b, k, axis=0), base_rows)
-                bs = self.trainer.batch_size
-                x = self._x[idx].reshape(Vp * k, n_steps, bs,
-                                         *self._x.shape[1:])
-                y = self._y[idx].reshape(Vp * k, n_steps, bs)
+                x, y = self._batches(data, idx, Vp * k, n_steps)
                 trained, _ = jax.vmap(self.trainer.multi_step)(rep, x, y)
 
                 def orbit_fold(carry, j):
@@ -611,7 +629,8 @@ class FusedExecutor:
 
             fn = jax.jit(event, donate_argnums=(0, 1))
             self._jit[key] = fn
-        return fn(params, bases, _h2d(visited, np.int32),
+        return fn(params, bases, self._data_local,
+                  _h2d(visited, np.int32),
                   _h2d(idx, np.int32),
                   _h2d(lam_rows, np.float32),
                   _h2d(rhos, np.float32), jnp.asarray(valid))
@@ -636,12 +655,9 @@ class FusedExecutor:
         key = ("fedspace", Np, n_steps)
         fn = self._jit.get(key)
         if fn is None:
-            def event(params, bases, sats, idx):
+            def event(params, bases, data, sats, idx):
                 rows = jax.tree.map(lambda b: b[sats], bases)
-                bs = self.trainer.batch_size
-                x = self._x[idx].reshape(Np, n_steps, bs,
-                                         *self._x.shape[1:])
-                y = self._y[idx].reshape(Np, n_steps, bs)
+                x, y = self._batches(data, idx, Np, n_steps)
                 trained, _ = jax.vmap(self.trainer.multi_step)(rows, x, y)
                 deltas = jax.tree.map(lambda t, r: t - r, trained, rows)
                 bases = jax.tree.map(
@@ -652,7 +668,8 @@ class FusedExecutor:
 
             fn = jax.jit(event, donate_argnums=1)
             self._jit[key] = fn
-        return fn(params, bases, _h2d(sats, np.int32),
+        return fn(params, bases, self._data_local,
+                  _h2d(sats, np.int32),
                   _h2d(idx, np.int32))
 
     def fedspace_flush(self, params: Any, stacked_deltas: Any,

@@ -18,7 +18,6 @@ os.environ["XLA_FLAGS"] = (
 
 import jax
 
-from repro.compat import set_mesh
 import jax.numpy as jnp
 import numpy as np
 
@@ -97,7 +96,7 @@ def run_single_pod():
             cfg = FedRoundConfig(cmap=cmap, partial_mode=mode,
                                  orbit_weighting="paper",
                                  ship_global_echo=(mode == "paper"))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 faithful = jax.jit(build_round(mesh, cfg, ex(params),
                                                kind="fedhap"))
                 fused = jax.jit(build_round(mesh, cfg, ex(params),
@@ -118,7 +117,7 @@ def run_single_pod():
         cfg = FedRoundConfig(cmap=cmap, partial_mode="exact",
                              orbit_weighting="global",
                              ship_global_echo=False)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             rd = jax.jit(build_round(mesh, cfg, ex(params), kind="fedhap"))
             new_e, _ = rd(params, sz_j, vis_j)
             fa = jax.jit(build_round(mesh, cfg, ex(params), kind="fedavg"))
@@ -129,7 +128,7 @@ def run_single_pod():
     visible = np.zeros(n, bool)
     visible[:4] = True
     cfg = FedRoundConfig(cmap=cmap)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         rd = jax.jit(build_round(mesh, cfg, ex(params), kind="fedhap"))
         new_p, stats = rd(params, jnp.ones(n), jnp.asarray(visible))
     assert float(stats["gate"]) == 0.0
@@ -152,7 +151,7 @@ def run_multi_pod():
         for hap_ring in (True, False):
             cfg = FedRoundConfig(cmap=cmap, partial_mode=mode,
                                  hap_ring=hap_ring, ship_global_echo=False)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 rd = jax.jit(build_round(mesh, cfg, ex(params), kind="fedhap"))
                 new_p, stats = rd(params, sz_j, vis_j)
             assert float(stats["gate"]) == 1.0

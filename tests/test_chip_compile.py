@@ -1,0 +1,82 @@
+"""Compile rehearsals for a described TPU v5e, with no chip attached.
+
+The TPU compiler is installed with jax; it compiles for a topology that
+is described rather than attached, and refuses what the chip would
+refuse (VMEM overflow, HBM overflow). These tests compile the main
+path's kernel, the Pallas ``fedagg`` fold, at the paper CNN's width and
+at every replica count the cells use: S=40 is the paper 5x8 shell, 200
+the per-chip shard of an 800-satellite constellation on four chips, 800
+that constellation on one.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library, and pytest
+workers that each collect this file must see the same tests. Keep every
+such compile in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.fedagg import MAX_BLOCK_P, fedagg, pick_block_p
+
+P_CNN = 1_663_370          # paper CNN parameter count
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # A compile for a described chip can be written to the persistent
+    # cache but never read back without one: keep the cache off here.
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.mark.parametrize("s", [1, 10, 40, 200, 800])
+def test_fedagg_compiles_for_v5e(one_chip, s):
+    x = jax.ShapeDtypeStruct((s, P_CNN), jnp.float32, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((s,), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda a, b: fedagg(a, b, interpret=False)).lower(x, w).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_block_p_follows_replica_count():
+    widths = [pick_block_p(s) for s in (1, 40, 80, 200, 800)]
+    assert widths == sorted(widths, reverse=True)
+    assert widths[0] == MAX_BLOCK_P and widths[-1] == 1024
+    assert all(w % 1024 == 0 for w in widths)
+    with pytest.raises(ValueError, match="MiB VMEM budget"):
+        pick_block_p(2048)
+
+
+def test_auto_block_p_is_bitwise_equal_to_widest():
+    """Each column's sum over S keeps its order whatever the tile width,
+    so the S-dependent width folds exactly what a 16384 tile folds. At
+    S=80 both fit, and they differ."""
+    s = 80
+    assert pick_block_p(s) < MAX_BLOCK_P
+    rng = np.random.default_rng(0)
+    p = 3 * MAX_BLOCK_P + 1000          # several tiles and a ragged tail
+    x = jnp.asarray(rng.standard_normal((s, p)).astype(np.float32))
+    w = jnp.asarray(rng.random(s).astype(np.float32))
+    auto = fedagg(x, w, interpret=True)
+    widest = fedagg(x, w, block_p=MAX_BLOCK_P, interpret=True)
+    np.testing.assert_array_equal(np.asarray(auto), np.asarray(widest))

@@ -120,6 +120,29 @@ class TestFusedVsPerRoundHistories:
             [e for _, e, _ in ref.history]
         assert len(fus.history) == len(ref.history) < QUICK["max_rounds"]
 
+    def test_round_program_takes_dataset_as_argument(self):
+        """A closed-over dataset is baked into the executable as a
+        constant, which makes the paper round program too large for a
+        persistent compile cache; it must arrive as an argument."""
+        eng = RoundEngine(SimConfig(strategy="fedhap", stations="one_hap",
+                                    **QUICK))
+        res = eng.run()
+        ex = eng.executor
+        (key, fn), = [(k, f) for k, f in ex._jit.items()
+                      if k[0] == "round"]
+        K, S, n_steps = key[1:]
+        spec = jax.ShapeDtypeStruct
+        params, data = jax.tree.map(lambda x: spec(x.shape, x.dtype),
+                                    (res.params, ex._data))
+        need = n_steps * eng.cfg.batch_size
+        compiled = fn.lower(
+            params, data, spec((K, S, need), np.int32),
+            spec((K, S), np.float32), spec((K,), np.bool_),
+            spec((K,), np.bool_)).compile()
+        data_bytes = sum(x.nbytes for x in ex._data)
+        assert compiled.memory_analysis().argument_size_in_bytes \
+            >= data_bytes
+
 
 class TestEvaluateSingleTransfer:
     @pytest.mark.parametrize("model", [MLP(MLP_CONFIG), CNN(CNN_CONFIG)],
