@@ -129,6 +129,7 @@ from typing import Any, Optional, Union
 
 import numpy as np
 
+from repro import obs
 from repro.configs.paper_cnn import CONFIG as CNN_CONFIG
 from repro.configs.paper_mlp import CONFIG as MLP_CONFIG
 from repro.core.treeops import tree_combine
@@ -286,6 +287,9 @@ class SimResult:
     rounds: int
     sim_hours: float
     params: Any = None        # final global model (device-resident)
+    # The run's spans and counters (``repro.obs``): dispatches, uploaded
+    # bytes, updates, plan seconds.
+    counters: dict = dataclasses.field(default_factory=dict)
 
     def time_to_accuracy(self, acc: float) -> Optional[float]:
         for t, _, a in self.history:
@@ -343,6 +347,10 @@ class RoundEngine:
     """Holds the physical world + dataset and drives one strategy."""
 
     def __init__(self, cfg: SimConfig):
+        with obs.span("engine.build"):
+            self._build_world(cfg)
+
+    def _build_world(self, cfg: SimConfig) -> None:
         self.cfg = cfg
         if cfg.shells:
             self.constellation = MultiShellConstellation(cfg.shells)
@@ -1081,8 +1089,12 @@ class RoundEngine:
         return tree_combine(stacked, np.asarray(weights, dtype=np.float32))
 
     def eval_and_record(self, s: RunState) -> None:
-        s.acc = self.trainer.evaluate(s.params, self.eval_images,
-                                      self.eval_labels)
+        """Host-path eval: the eval set goes to the device on every call
+        (``exec.upload_bytes``), counted as one dispatch."""
+        with obs.span("sim.eval"):
+            s.acc = self.trainer.evaluate(s.params, self.eval_images,
+                                          self.eval_labels)
+        obs.count("exec.dispatches")
         s.history.append((s.t / 3600.0, s.events, s.acc))
 
     # ----------------------------------------------------- checkpointing
@@ -1114,6 +1126,7 @@ class RoundEngine:
         s.events = int(meta["events"])
         s.history = [(float(t), int(e), float(a))
                      for t, e, a in meta["history"]]
+        obs.count("updates", -s.events)     # not gained in this run
         self.rng.bit_generator.state = meta["rng_state"]
         if meta.get("plane_calls") is not None and \
                 hasattr(self.client_plane, "_calls"):
@@ -1186,27 +1199,30 @@ class RoundEngine:
                     "through the fused driver (fused=True)")
             self._ckpt = _CkptState(checkpoint_dir,
                                     max(1, int(checkpoint_every)), resume)
-        s = RunState(params=self.trainer.init(cfg.seed))
-        try:
-            if use_fused:
-                if self._fused_cm is not None:
-                    with self._fused_cm():
+        with obs.run() as counters:
+            s = RunState(params=self.trainer.init(cfg.seed))
+            try:
+                if use_fused:
+                    if self._fused_cm is not None:
+                        with self._fused_cm():
+                            strat.run_fused(self, s)
+                    else:
                         strat.run_fused(self, s)
                 else:
-                    strat.run_fused(self, s)
-            else:
-                loaded = self.ckpt_resume(s, {"params": s.params})
-                if loaded is not None:
-                    s.params = loaded["params"]
-                while (s.events < cfg.max_rounds and s.t <= self.horizon_s
-                       and s.acc < cfg.target_accuracy):
-                    if not strat.step(self, s):
-                        break
-                    self.ckpt_tick(s, {"params": s.params})
-        finally:
-            self._ckpt = None
+                    loaded = self.ckpt_resume(s, {"params": s.params})
+                    if loaded is not None:
+                        s.params = loaded["params"]
+                    while (s.events < cfg.max_rounds
+                           and s.t <= self.horizon_s
+                           and s.acc < cfg.target_accuracy):
+                        if not strat.step(self, s):
+                            break
+                        self.ckpt_tick(s, {"params": s.params})
+            finally:
+                self._ckpt = None
+            obs.count("updates", s.events)
         return SimResult(s.history, s.acc, len(s.history), s.t / 3600.0,
-                         s.params)
+                         s.params, dict(counters))
 
 
 # The engine is API-compatible with the pre-registry monolith.

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.mesh_round import sharded_fold
 from repro.core.treeops import (
     tree_broadcast,
@@ -81,13 +82,12 @@ def tree_combine_many(stacked: Any, weight_rows: Any) -> Any:
     return jax.tree.map(lambda x: jnp.einsum("ks,s...->k...", w, x), stacked)
 
 
-def _h2d(x: Any, dtype: Any) -> jnp.ndarray:
-    """Explicit host->device upload of a plan tensor: cast in numpy
-    first so the device copy is dtype-preserving. A *casting*
-    ``jnp.asarray(x, dtype)`` counts as an implicit transfer under
-    ``jax.transfer_guard`` and the sanitizer (repro.debug.sanitize)
-    runs the block loop with transfers disallowed."""
-    return jnp.asarray(np.asarray(x, dtype))
+# The event tensors of a cycle block, in the programs' argument order,
+# with the dtype each goes to the device as.
+_EVENT_TENSORS = (("l", np.int32), ("idx", np.int32), ("lam", np.float32),
+                  ("rhos", np.float32), ("keep", np.float32),
+                  ("slot", np.int32), ("flush", bool), ("do_eval", bool),
+                  ("valid", bool))
 
 
 class FusedExecutor:
@@ -133,8 +133,34 @@ class FusedExecutor:
         self._data = self._replicate(self._data_local)
 
     # ------------------------------------------------------------ basics
+    def _call(self, key: tuple, build: Any, args: tuple,
+              uploads: tuple = ()) -> Any:
+        """Every program call goes through here. Runs the program cached
+        under ``key`` (made by ``build()`` on first use) on the device
+        arguments ``args`` followed by ``uploads``, ``(host array,
+        dtype)`` pairs sent to the device here (``obs.upload``). A
+        program's first call, which traces and compiles it (or reads it
+        from the persistent cache), is an ``exec.build`` span; every
+        later one an ``exec.dispatch`` span. Either counts one
+        dispatch."""
+        fn = self._jit.get(key)
+        name = "exec.dispatch"
+        if fn is None:
+            fn = self._jit[key] = build()
+            name = "exec.build"
+        with obs.span(name):
+            out = fn(*args, *(obs.upload(x, dt) for x, dt in uploads))
+        obs.count("exec.dispatches")
+        return out
+
     def _fold(self, stacked: Any, weights: Any) -> Any:
-        return fold_stacked_tree(stacked, weights, self.use_pallas)
+        with obs.scope("fold"):
+            return fold_stacked_tree(stacked, weights, self.use_pallas)
+
+    def _sharded_fold(self, stacked: Any, weights: Any) -> Any:
+        with obs.scope("fold"):
+            return sharded_fold(stacked, weights, ("data",),
+                                self.use_pallas)
 
     def _replicate(self, tree: Any) -> Any:
         """Commit a param tree replicated over the mesh (no-op without
@@ -175,8 +201,9 @@ class FusedExecutor:
             pred = jnp.argmax(model.forward(params, x), axis=-1)
             return jnp.sum((pred == y).astype(jnp.float32))
 
-        correct = jnp.sum(jax.lax.map(chunk_correct, (ex, ey)))
-        return correct / jnp.float32(self._eval_n)
+        with obs.scope("eval"):
+            correct = jnp.sum(jax.lax.map(chunk_correct, (ex, ey)))
+            return correct / jnp.float32(self._eval_n)
 
     def _nan_acc(self, params: Any) -> jax.Array:
         return jnp.full((), jnp.nan, jnp.float32)
@@ -194,34 +221,31 @@ class FusedExecutor:
         """The megastep's train half: device gather of the sampled
         mini-batch indices + one vmapped SGD burst over ``n_rep``
         replicas broadcast from ``base`` inside jit."""
-        x, y = self._batches(data, idx, n_rep, n_steps)
-        trained, _ = jax.vmap(self.trainer.multi_step)(
-            tree_broadcast(base, n_rep), x, y)
+        with obs.scope("train"):
+            x, y = self._batches(data, idx, n_rep, n_steps)
+            trained, _ = jax.vmap(self.trainer.multi_step)(
+                tree_broadcast(base, n_rep), x, y)
         return trained
 
     def broadcast_rows(self, params: Any, n: int) -> Any:
         """Materialized (n, ...) stacked copies of ``params`` on device
         (per-orbit / per-satellite base-model tables)."""
-        key = ("bcast", n)
-        fn = self._jit.get(key)
-        if fn is None:
-            fn = jax.jit(lambda p: jax.tree.map(
-                lambda x: jnp.tile(x[None], (n,) + (1,) * x.ndim), p))
-            self._jit[key] = fn
-        return fn(params)
+        return self._call(
+            ("bcast", n),
+            lambda: jax.jit(lambda p: jax.tree.map(
+                lambda x: jnp.tile(x[None], (n,) + (1,) * x.ndim), p)),
+            (params,))
 
     def zero_rows(self, params: Any, n: int) -> Any:
         """(n, ...) zero-filled stacked tree matching ``params`` leaves,
         built inside jit (an eager ``jnp.zeros`` is a host->device
         scalar transfer, which the sanitizer's transfer guard rejects
         in the block loop)."""
-        key = ("zeros", n)
-        fn = self._jit.get(key)
-        if fn is None:
-            fn = jax.jit(lambda p: jax.tree.map(
-                lambda x: jnp.zeros((n,) + x.shape, x.dtype), p))
-            self._jit[key] = fn
-        return fn(params)
+        return self._call(
+            ("zeros", n),
+            lambda: jax.jit(lambda p: jax.tree.map(
+                lambda x: jnp.zeros((n,) + x.shape, x.dtype), p)),
+            (params,))
 
     # -------------------------------------------- synchronous round family
     def run_block(self, params: Any, idx: np.ndarray, mu: np.ndarray,
@@ -249,32 +273,30 @@ class FusedExecutor:
                                            valid)
         K, S, need = idx.shape
         n_steps = need // self.trainer.batch_size
-        key = ("round", K, S, n_steps)
-        fn = self._jit.get(key)
-        if fn is None:
-            def block(params, data, idx, mu, do_eval, valid):
-                def body(p, inp):
-                    idx_r, mu_r, ev, va = inp
 
-                    def megastep(p):
-                        trained = self._train(data, p, idx_r, S, n_steps)
-                        return self._fold(trained, mu_r)
+        def block(params, data, idx, mu, do_eval, valid):
+            def body(p, inp):
+                idx_r, mu_r, ev, va = inp
 
-                    p = jax.lax.cond(va, megastep, lambda q: q, p)
-                    acc = jax.lax.cond(
-                        ev & va, functools.partial(self._device_acc, data),
-                        self._nan_acc, p)
-                    return p, acc
+                def megastep(p):
+                    trained = self._train(data, p, idx_r, S, n_steps)
+                    return self._fold(trained, mu_r)
 
-                return jax.lax.scan(body, params,
-                                    (idx, mu, do_eval, valid))
+                p = jax.lax.cond(va, megastep, lambda q: q, p)
+                acc = jax.lax.cond(
+                    ev & va, functools.partial(self._device_acc, data),
+                    self._nan_acc, p)
+                return p, acc
 
-            fn = jax.jit(block, donate_argnums=0)
-            self._jit[key] = fn
-        params, accs = fn(params, self._data, _h2d(idx, np.int32),
-                          _h2d(mu, np.float32),
-                          jnp.asarray(do_eval), jnp.asarray(valid))
-        return params, np.asarray(accs)
+            return jax.lax.scan(body, params, (idx, mu, do_eval, valid))
+
+        params, accs = self._call(
+            ("round", K, S, n_steps),
+            lambda: jax.jit(block, donate_argnums=0),
+            (params, self._data),
+            ((idx, np.int32), (mu, np.float32), (do_eval, bool),
+             (valid, bool)))
+        return params, obs.fetch(accs)
 
     def _run_block_sharded(self, params: Any, idx: np.ndarray,
                            mu: np.ndarray, do_eval: np.ndarray,
@@ -299,50 +321,42 @@ class FusedExecutor:
         K, Sp, need = idx.shape
         s_loc = Sp // D
         n_steps = need // self.trainer.batch_size
-        key = ("round_sharded", K, Sp, n_steps)
-        fn = self._jit.get(key)
-        if fn is None:
-            def block(params, data, idx, mu, do_eval, valid):
-                def body(p, inp):
-                    idx_r, mu_r, ev, va = inp
 
-                    def megastep(p):
-                        trained = self._train(data, p, idx_r, s_loc,
-                                              n_steps)
-                        return sharded_fold(trained, mu_r, ("data",),
-                                            self.use_pallas)
+        def block(params, data, idx, mu, do_eval, valid):
+            def body(p, inp):
+                idx_r, mu_r, ev, va = inp
 
-                    p = jax.lax.cond(va, megastep, lambda q: q, p)
-                    acc = jax.lax.cond(
-                        ev & va, functools.partial(self._device_acc, data),
-                        self._nan_acc, p)
-                    return p, acc
+                def megastep(p):
+                    trained = self._train(data, p, idx_r, s_loc, n_steps)
+                    return self._sharded_fold(trained, mu_r)
 
-                return jax.lax.scan(body, params,
-                                    (idx, mu, do_eval, valid))
+                p = jax.lax.cond(va, megastep, lambda q: q, p)
+                acc = jax.lax.cond(
+                    ev & va, functools.partial(self._device_acc, data),
+                    self._nan_acc, p)
+                return p, acc
 
-            sharded = jax.shard_map(
-                block, mesh=self.mesh,
-                in_specs=(P(), P(), P(None, "data", None),
-                          P(None, "data"), P(), P()),
-                out_specs=(P(), P()), check_vma=False)
-            fn = jax.jit(sharded, donate_argnums=0)
-            self._jit[key] = fn
-        params, accs = fn(self._replicate(params), self._data,
-                          _h2d(idx, np.int32),
-                          _h2d(mu, np.float32),
-                          jnp.asarray(do_eval), jnp.asarray(valid))
-        return params, np.asarray(accs)
+            return jax.lax.scan(body, params, (idx, mu, do_eval, valid))
+
+        sharded = jax.shard_map(
+            block, mesh=self.mesh,
+            in_specs=(P(), P(), P(None, "data", None), P(None, "data"),
+                      P(), P()),
+            out_specs=(P(), P()), check_vma=False)
+        params, accs = self._call(
+            ("round_sharded", K, Sp, n_steps),
+            lambda: jax.jit(sharded, donate_argnums=0),
+            (self._replicate(params), self._data),
+            ((idx, np.int32), (mu, np.float32), (do_eval, bool),
+             (valid, bool)))
+        return params, obs.fetch(accs)
 
     def fold_block(self, stacked: Any, weight_rows: np.ndarray) -> Any:
         """K planned folds of a fixed stacked tree as one dispatch (the
         schedule-tensor batched aggregation; see tree_combine_many)."""
-        key = ("fold_block",)
-        fn = self._jit.get(key)
-        if fn is None:
-            fn = jax.jit(tree_combine_many)
-            self._jit[key] = fn
-        return fn(stacked, _h2d(weight_rows, np.float32))
+        return self._call(("fold_block",),
+                          lambda: jax.jit(tree_combine_many), (stacked,),
+                          ((weight_rows, np.float32),))
 
     # ------------------------------------------------- routed event family
     def cycle_block(self, params: Any, bases: Any, buf: Any,
@@ -371,61 +385,51 @@ class FusedExecutor:
         K, k, need = ev["idx"].shape
         B = ev["rhos"].shape[1]
         n_steps = need // self.trainer.batch_size
-        key = ("cycle", K, k, B, n_steps)
-        fn = self._jit.get(key)
-        if fn is None:
-            def block(params, bases, buf, data, l, idx, lam, rhos, keep,
-                      slot, flush, do_eval, valid):
-                def body(carry, inp):
-                    g, bases, buf = carry
-                    (l_e, idx_e, lam_e, rhos_e, keep_e, slot_e, fl, evf,
-                     va) = inp
 
-                    def event(args):
-                        g, bases, buf = args
-                        base = tree_row(bases, l_e)
-                        trained = self._train(data, base, idx_e, k,
-                                              n_steps)
-                        orbit_model = self._fold(trained, lam_e)
-                        buf = tree_set_row(buf, slot_e, orbit_model)
+        def block(params, bases, buf, data, l, idx, lam, rhos, keep,
+                  slot, flush, do_eval, valid):
+            def body(carry, inp):
+                g, bases, buf = carry
+                (l_e, idx_e, lam_e, rhos_e, keep_e, slot_e, fl, evf,
+                 va) = inp
 
-                        def do_flush(g):
-                            return jax.tree.map(
-                                lambda gg, bb: keep_e * gg + jnp.einsum(
-                                    "s,s...->...", rhos_e, bb),
-                                g, buf)
+                def event(args):
+                    g, bases, buf = args
+                    base = tree_row(bases, l_e)
+                    trained = self._train(data, base, idx_e, k,
+                                          n_steps)
+                    orbit_model = self._fold(trained, lam_e)
+                    buf = tree_set_row(buf, slot_e, orbit_model)
 
-                        g = jax.lax.cond(fl, do_flush, lambda q: q, g)
-                        bases = tree_set_row(bases, l_e, g)
-                        return g, bases, buf
+                    def do_flush(g):
+                        return jax.tree.map(
+                            lambda gg, bb: keep_e * gg + jnp.einsum(
+                                "s,s...->...", rhos_e, bb),
+                            g, buf)
 
-                    g, bases, buf = jax.lax.cond(
-                        va, event, lambda a: a, (g, bases, buf))
-                    acc = jax.lax.cond(
-                        evf & va, functools.partial(self._device_acc, data),
-                        self._nan_acc, g)
-                    return (g, bases, buf), acc
+                    g = jax.lax.cond(fl, do_flush, lambda q: q, g)
+                    bases = tree_set_row(bases, l_e, g)
+                    return g, bases, buf
 
-                (g, bases, buf), accs = jax.lax.scan(
-                    body, (params, bases, buf),
-                    (l, idx, lam, rhos, keep, slot, flush, do_eval,
-                     valid))
-                return g, bases, buf, accs
+                g, bases, buf = jax.lax.cond(
+                    va, event, lambda a: a, (g, bases, buf))
+                acc = jax.lax.cond(
+                    evf & va, functools.partial(self._device_acc, data),
+                    self._nan_acc, g)
+                return (g, bases, buf), acc
 
-            fn = jax.jit(block, donate_argnums=(0, 1, 2))
-            self._jit[key] = fn
-        g, bases, buf, accs = fn(
-            params, bases, buf, self._data,
-            _h2d(ev["l"], np.int32),
-            _h2d(ev["idx"], np.int32),
-            _h2d(ev["lam"], np.float32),
-            _h2d(ev["rhos"], np.float32),
-            _h2d(ev["keep"], np.float32),
-            _h2d(ev["slot"], np.int32),
-            jnp.asarray(ev["flush"]),
-            jnp.asarray(ev["do_eval"]),
-            jnp.asarray(ev["valid"]))
-        return g, bases, buf, np.asarray(accs)
+            (g, bases, buf), accs = jax.lax.scan(
+                body, (params, bases, buf),
+                (l, idx, lam, rhos, keep, slot, flush, do_eval,
+                 valid))
+            return g, bases, buf, accs
+
+        g, bases, buf, accs = self._call(
+            ("cycle", K, k, B, n_steps),
+            lambda: jax.jit(block, donate_argnums=(0, 1, 2)),
+            (params, bases, buf, self._data),
+            tuple((ev[name], dt) for name, dt in _EVENT_TENSORS))
+        return g, bases, buf, obs.fetch(accs)
 
     def _cycle_block_sharded(self, params: Any, bases: Any, buf: Any,
                              ev: dict[str, np.ndarray], sat_axes: tuple):
@@ -445,69 +449,57 @@ class FusedExecutor:
         k_loc = kp // D
         B = ev["rhos"].shape[1]
         n_steps = need // self.trainer.batch_size
-        key = ("cycle_sharded", K, kp, B, n_steps)
-        fn = self._jit.get(key)
-        if fn is None:
-            def block(params, bases, buf, data, l, idx, lam, rhos, keep,
-                      slot, flush, do_eval, valid):
-                def body(carry, inp):
-                    g, bases, buf = carry
-                    (l_e, idx_e, lam_e, rhos_e, keep_e, slot_e, fl, evf,
-                     va) = inp
 
-                    def event(args):
-                        g, bases, buf = args
-                        base = tree_row(bases, l_e)
-                        trained = self._train(data, base, idx_e, k_loc,
-                                              n_steps)
-                        orbit_model = sharded_fold(
-                            trained, lam_e, ("data",), self.use_pallas)
-                        buf = tree_set_row(buf, slot_e, orbit_model)
+        def block(params, bases, buf, data, l, idx, lam, rhos, keep,
+                  slot, flush, do_eval, valid):
+            def body(carry, inp):
+                g, bases, buf = carry
+                (l_e, idx_e, lam_e, rhos_e, keep_e, slot_e, fl, evf,
+                 va) = inp
 
-                        def do_flush(g):
-                            return jax.tree.map(
-                                lambda gg, bb: keep_e * gg + jnp.einsum(
-                                    "s,s...->...", rhos_e, bb),
-                                g, buf)
+                def event(args):
+                    g, bases, buf = args
+                    base = tree_row(bases, l_e)
+                    trained = self._train(data, base, idx_e, k_loc,
+                                          n_steps)
+                    orbit_model = self._sharded_fold(trained, lam_e)
+                    buf = tree_set_row(buf, slot_e, orbit_model)
 
-                        g = jax.lax.cond(fl, do_flush, lambda q: q, g)
-                        bases = tree_set_row(bases, l_e, g)
-                        return g, bases, buf
+                    def do_flush(g):
+                        return jax.tree.map(
+                            lambda gg, bb: keep_e * gg + jnp.einsum(
+                                "s,s...->...", rhos_e, bb),
+                            g, buf)
 
-                    g, bases, buf = jax.lax.cond(
-                        va, event, lambda a: a, (g, bases, buf))
-                    acc = jax.lax.cond(
-                        evf & va, functools.partial(self._device_acc, data),
-                        self._nan_acc, g)
-                    return (g, bases, buf), acc
+                    g = jax.lax.cond(fl, do_flush, lambda q: q, g)
+                    bases = tree_set_row(bases, l_e, g)
+                    return g, bases, buf
 
-                (g, bases, buf), accs = jax.lax.scan(
-                    body, (params, bases, buf),
-                    (l, idx, lam, rhos, keep, slot, flush, do_eval,
-                     valid))
-                return g, bases, buf, accs
+                g, bases, buf = jax.lax.cond(
+                    va, event, lambda a: a, (g, bases, buf))
+                acc = jax.lax.cond(
+                    evf & va, functools.partial(self._device_acc, data),
+                    self._nan_acc, g)
+                return (g, bases, buf), acc
 
-            sharded = jax.shard_map(
-                block, mesh=self.mesh,
-                in_specs=(P(), P(), P(), P(), P(), P(None, "data", None),
-                          P(None, "data"), P(), P(), P(), P(), P(),
-                          P()),
-                out_specs=(P(), P(), P(), P()), check_vma=False)
-            fn = jax.jit(sharded, donate_argnums=(0, 1, 2))
-            self._jit[key] = fn
-        g, bases, buf, accs = fn(
-            self._replicate(params), self._replicate(bases),
-            self._replicate(buf), self._data,
-            _h2d(ev["l"], np.int32),
-            _h2d(ev["idx"], np.int32),
-            _h2d(ev["lam"], np.float32),
-            _h2d(ev["rhos"], np.float32),
-            _h2d(ev["keep"], np.float32),
-            _h2d(ev["slot"], np.int32),
-            jnp.asarray(ev["flush"]),
-            jnp.asarray(ev["do_eval"]),
-            jnp.asarray(ev["valid"]))
-        return g, bases, buf, np.asarray(accs)
+            (g, bases, buf), accs = jax.lax.scan(
+                body, (params, bases, buf),
+                (l, idx, lam, rhos, keep, slot, flush, do_eval,
+                 valid))
+            return g, bases, buf, accs
+
+        sharded = jax.shard_map(
+            block, mesh=self.mesh,
+            in_specs=(P(), P(), P(), P(), P(), P(None, "data", None),
+                      P(None, "data"), P(), P(), P(), P(), P(), P()),
+            out_specs=(P(), P(), P(), P()), check_vma=False)
+        g, bases, buf, accs = self._call(
+            ("cycle_sharded", K, kp, B, n_steps),
+            lambda: jax.jit(sharded, donate_argnums=(0, 1, 2)),
+            (self._replicate(params), self._replicate(bases),
+             self._replicate(buf), self._data),
+            tuple((ev[name], dt) for name, dt in _EVENT_TENSORS))
+        return g, bases, buf, obs.fetch(accs)
 
     def cycle_fold_block(self, params: Any, buf: Any, stacked_k: Any,
                          ev: dict[str, np.ndarray]):
@@ -518,49 +510,42 @@ class FusedExecutor:
         Returns ``(params, buf)``; no eval."""
         K = len(ev["l"])
         B = ev["rhos"].shape[1]
-        key = ("cycle_fold", K, B)
-        fn = self._jit.get(key)
-        if fn is None:
-            def block(params, buf, stacked_k, lam, rhos, keep, slot,
-                      flush, valid):
-                def body(carry, inp):
-                    g, buf = carry
-                    lam_e, rhos_e, keep_e, slot_e, fl, va = inp
 
-                    def event(args):
-                        g, buf = args
-                        orbit_model = self._fold(stacked_k, lam_e)
-                        buf = tree_set_row(buf, slot_e, orbit_model)
+        def block(params, buf, stacked_k, lam, rhos, keep, slot, flush,
+                  valid):
+            def body(carry, inp):
+                g, buf = carry
+                lam_e, rhos_e, keep_e, slot_e, fl, va = inp
 
-                        def do_flush(g):
-                            return jax.tree.map(
-                                lambda gg, bb: keep_e * gg + jnp.einsum(
-                                    "s,s...->...", rhos_e, bb),
-                                g, buf)
+                def event(args):
+                    g, buf = args
+                    orbit_model = self._fold(stacked_k, lam_e)
+                    buf = tree_set_row(buf, slot_e, orbit_model)
 
-                        g = jax.lax.cond(fl, do_flush, lambda q: q, g)
-                        return g, buf
+                    def do_flush(g):
+                        return jax.tree.map(
+                            lambda gg, bb: keep_e * gg + jnp.einsum(
+                                "s,s...->...", rhos_e, bb),
+                            g, buf)
 
-                    g, buf = jax.lax.cond(va, event, lambda a: a,
-                                          (g, buf))
-                    return (g, buf), None
+                    g = jax.lax.cond(fl, do_flush, lambda q: q, g)
+                    return g, buf
 
-                (g, buf), _ = jax.lax.scan(
-                    body, (params, buf),
-                    (lam, rhos, keep, slot, flush, valid))
-                return g, buf
+                g, buf = jax.lax.cond(va, event, lambda a: a, (g, buf))
+                return (g, buf), None
 
-            # No donation: the wallclock benches re-drive from the same
-            # initial params when timing warm vs steady-state.
-            fn = jax.jit(block)
-            self._jit[key] = fn
-        return fn(params, buf, stacked_k,
-                  _h2d(ev["lam"], np.float32),
-                  _h2d(ev["rhos"], np.float32),
-                  _h2d(ev["keep"], np.float32),
-                  _h2d(ev["slot"], np.int32),
-                  jnp.asarray(ev["flush"]),
-                  jnp.asarray(ev["valid"]))
+            (g, buf), _ = jax.lax.scan(
+                body, (params, buf), (lam, rhos, keep, slot, flush, valid))
+            return g, buf
+
+        # No donation: the wallclock benches re-drive from the same
+        # initial params when timing warm vs steady-state.
+        return self._call(
+            ("cycle_fold", K, B), lambda: jax.jit(block),
+            (params, buf, stacked_k),
+            ((ev["lam"], np.float32), (ev["rhos"], np.float32),
+             (ev["keep"], np.float32), (ev["slot"], np.int32),
+             (ev["flush"], bool), (ev["valid"], bool)))
 
     # ------------------------------------------- tick-driven baselines
     #
@@ -596,44 +581,42 @@ class FusedExecutor:
                                        np.zeros((pad, k))])
             rhos = np.concatenate([rhos, np.zeros(pad)])
         valid = np.arange(Vp) < V
-        key = ("fedsat", Vp, k, n_steps)
-        fn = self._jit.get(key)
-        if fn is None:
-            def event(params, bases, data, visited, idx, lam_rows, rhos,
-                      valid):
+
+        def event(params, bases, data, visited, idx, lam_rows, rhos, valid):
+            with obs.scope("train"):
                 base_rows = jax.tree.map(lambda b: b[visited], bases)
                 rep = jax.tree.map(
                     lambda b: jnp.repeat(b, k, axis=0), base_rows)
                 x, y = self._batches(data, idx, Vp * k, n_steps)
                 trained, _ = jax.vmap(self.trainer.multi_step)(rep, x, y)
 
-                def orbit_fold(carry, j):
-                    g, bases = carry
-                    rows = jax.tree.map(
-                        lambda t: jax.lax.dynamic_slice_in_dim(
-                            t, j * k, k), trained)
-                    orbit_model = self._fold(rows, lam_rows[j])
-                    rho = jnp.where(valid[j], rhos[j], 0.0)
-                    g = jax.tree.map(
-                        lambda gg, oo: (1.0 - rho) * gg + rho * oo,
-                        g, orbit_model)
-                    bases = jax.lax.cond(
-                        valid[j],
-                        lambda a: tree_set_row(a[0], visited[j], a[1]),
-                        lambda a: a[0], (bases, g))
-                    return (g, bases), None
+            def orbit_fold(carry, j):
+                g, bases = carry
+                rows = jax.tree.map(
+                    lambda t: jax.lax.dynamic_slice_in_dim(t, j * k, k),
+                    trained)
+                orbit_model = self._fold(rows, lam_rows[j])
+                rho = jnp.where(valid[j], rhos[j], 0.0)
+                g = jax.tree.map(
+                    lambda gg, oo: (1.0 - rho) * gg + rho * oo,
+                    g, orbit_model)
+                bases = jax.lax.cond(
+                    valid[j],
+                    lambda a: tree_set_row(a[0], visited[j], a[1]),
+                    lambda a: a[0], (bases, g))
+                return (g, bases), None
 
+            with obs.scope("fold"):
                 (g, bases), _ = jax.lax.scan(
                     orbit_fold, (params, bases), jnp.arange(Vp))
-                return g, bases
+            return g, bases
 
-            fn = jax.jit(event, donate_argnums=(0, 1))
-            self._jit[key] = fn
-        return fn(params, bases, self._data_local,
-                  _h2d(visited, np.int32),
-                  _h2d(idx, np.int32),
-                  _h2d(lam_rows, np.float32),
-                  _h2d(rhos, np.float32), jnp.asarray(valid))
+        return self._call(
+            ("fedsat", Vp, k, n_steps),
+            lambda: jax.jit(event, donate_argnums=(0, 1)),
+            (params, bases, self._data_local),
+            ((visited, np.int32), (idx, np.int32), (lam_rows, np.float32),
+             (rhos, np.float32), (valid, bool)))
 
     def fedspace_train(self, params: Any, bases: Any, sats: np.ndarray,
                        idx: np.ndarray):
@@ -652,25 +635,24 @@ class FusedExecutor:
             # the same value; the delta rows get weight 0 at flush.
             sats = np.concatenate([sats, np.repeat(sats[:1], pad)])
             idx = np.concatenate([idx, np.tile(idx[:1], (pad, 1))])
-        key = ("fedspace", Np, n_steps)
-        fn = self._jit.get(key)
-        if fn is None:
-            def event(params, bases, data, sats, idx):
-                rows = jax.tree.map(lambda b: b[sats], bases)
+
+        def event(params, bases, data, sats, idx):
+            rows = jax.tree.map(lambda b: b[sats], bases)
+            with obs.scope("train"):
                 x, y = self._batches(data, idx, Np, n_steps)
                 trained, _ = jax.vmap(self.trainer.multi_step)(rows, x, y)
-                deltas = jax.tree.map(lambda t, r: t - r, trained, rows)
-                bases = jax.tree.map(
-                    lambda b, p: b.at[sats].set(
-                        jnp.broadcast_to(p[None], (Np,) + p.shape)),
-                    bases, params)
-                return deltas, bases
+            deltas = jax.tree.map(lambda t, r: t - r, trained, rows)
+            bases = jax.tree.map(
+                lambda b, p: b.at[sats].set(
+                    jnp.broadcast_to(p[None], (Np,) + p.shape)),
+                bases, params)
+            return deltas, bases
 
-            fn = jax.jit(event, donate_argnums=1)
-            self._jit[key] = fn
-        return fn(params, bases, self._data_local,
-                  _h2d(sats, np.int32),
-                  _h2d(idx, np.int32))
+        return self._call(
+            ("fedspace", Np, n_steps),
+            lambda: jax.jit(event, donate_argnums=1),
+            (params, bases, self._data_local),
+            ((sats, np.int32), (idx, np.int32)))
 
     def fedspace_flush(self, params: Any, stacked_deltas: Any,
                        wts: np.ndarray):
@@ -688,25 +670,22 @@ class FusedExecutor:
             # which the sanitizer's guard rejects in the block loop.
             # Pad programs are keyed per (B, Bp) but trivial; the
             # expensive fold below stays O(log B) compiles.
-            pkey = ("pad_rows", B, Bp)
-            pfn = self._jit.get(pkey)
-            if pfn is None:
-                pfn = jax.jit(lambda t: jax.tree.map(
+            stacked_deltas = self._call(
+                ("pad_rows", B, Bp),
+                lambda: jax.jit(lambda t: jax.tree.map(
                     lambda x: jnp.concatenate(
                         [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)]),
-                    t))
-                self._jit[pkey] = pfn
-            stacked_deltas = pfn(stacked_deltas)
-        key = ("fedspace_flush", Bp)
-        fn = self._jit.get(key)
-        if fn is None:
-            def flush(params, stacked, wts):
-                upd = self._fold(stacked, wts)
-                return jax.tree.map(lambda p, u: p + u, params, upd)
+                    t)),
+                (stacked_deltas,))
 
-            fn = jax.jit(flush, donate_argnums=0)
-            self._jit[key] = fn
-        return fn(params, stacked_deltas, _h2d(wts, np.float32))
+        def flush(params, stacked, wts):
+            upd = self._fold(stacked, wts)
+            return jax.tree.map(lambda p, u: p + u, params, upd)
+
+        return self._call(
+            ("fedspace_flush", Bp),
+            lambda: jax.jit(flush, donate_argnums=0),
+            (params, stacked_deltas), ((wts, np.float32),))
 
 
 __all__ = ["FusedExecutor", "tree_combine_many"]

@@ -39,6 +39,7 @@ from typing import Any, Callable, Dict, Optional, Tuple, Type
 
 import numpy as np
 
+from repro import obs
 from repro.core.weights import staleness_discount
 
 _REGISTRY: Dict[str, Type["Strategy"]] = {}
@@ -171,40 +172,43 @@ class RoundStrategy(Strategy):
             s.params = loaded["params"]
         while (s.events < cfg.max_rounds and s.t <= eng.horizon_s
                and s.acc < cfg.target_accuracy):
-            # Plan ahead: chain K rounds (plans are param-independent).
-            plans, t_starts, t, terminal = [], [], s.t, False
-            while (len(plans) < K and s.events + len(plans) < cfg.max_rounds
-                   and t <= eng.horizon_s):
-                plan = self.plan_round(eng, t)
-                if plan is None:
-                    terminal = True
-                    break
-                plans.append(plan)
-                t_starts.append(t)
-                t = plan.t_next
-            if not plans:
-                s.t = eng.horizon_s + 1.0
-                return
-            # Schedule tensors (padded to the fixed block size K) + the
-            # host-resolved batch indices (same plane stream as `step`:
-            # one resolve per planned round, at that round's start time).
-            n = len(plans)
-            idx = np.zeros((K, n_sats, need), dtype=np.int64)
-            for i in range(n):
-                idx[i] = eng.sample_indices(all_clients, t_starts[i])
-            mu = np.zeros((K, n_sats), dtype=np.float32)
-            do_eval = np.zeros(K, dtype=bool)
-            fold_ok = np.zeros(K, dtype=bool)
-            for i, plan in enumerate(plans):
-                mu[i] = plan.mu
-                fold_ok[i] = bool(np.any(plan.mu))
-                do_eval[i] = self.eval_due(cfg, s.events + i + 1)
-            # Rounds that lost every upload (all-zero mu) invalidate
-            # their scan slot: the device carries params through and
-            # skips the on-device eval — the existing dead-row
-            # machinery, no fault-specific executor path. Their due
-            # evals run host-side below on the carried params.
-            valid = (np.arange(K) < n) & fold_ok
+            with obs.span("sim.plan"):
+                # Plan ahead: chain K rounds (plans are param-independent).
+                plans, t_starts, t, terminal = [], [], s.t, False
+                while (len(plans) < K
+                       and s.events + len(plans) < cfg.max_rounds
+                       and t <= eng.horizon_s):
+                    plan = self.plan_round(eng, t)
+                    if plan is None:
+                        terminal = True
+                        break
+                    plans.append(plan)
+                    t_starts.append(t)
+                    t = plan.t_next
+                if not plans:
+                    s.t = eng.horizon_s + 1.0
+                    return
+                # Schedule tensors (padded to the fixed block size K) +
+                # the host-resolved batch indices (same plane stream as
+                # `step`: one resolve per planned round, at that round's
+                # start time).
+                n = len(plans)
+                idx = np.zeros((K, n_sats, need), dtype=np.int64)
+                for i in range(n):
+                    idx[i] = eng.sample_indices(all_clients, t_starts[i])
+                mu = np.zeros((K, n_sats), dtype=np.float32)
+                do_eval = np.zeros(K, dtype=bool)
+                fold_ok = np.zeros(K, dtype=bool)
+                for i, plan in enumerate(plans):
+                    mu[i] = plan.mu
+                    fold_ok[i] = bool(np.any(plan.mu))
+                    do_eval[i] = self.eval_due(cfg, s.events + i + 1)
+                # Rounds that lost every upload (all-zero mu) invalidate
+                # their scan slot: the device carries params through and
+                # skips the on-device eval — the existing dead-row
+                # machinery, no fault-specific executor path. Their due
+                # evals run host-side below on the carried params.
+                valid = (np.arange(K) < n) & fold_ok
             s.params, accs = ex.run_block(s.params, idx, mu,
                                           do_eval & fold_ok, valid)
             # Host side: history + termination between blocks only.
@@ -452,41 +456,42 @@ class CycleStrategy(Strategy):
             if not st["inflight"]:
                 s.t = eng.horizon_s + 1.0
                 return
-            events = self.plan_events(eng, st, K,
-                                      cfg.max_rounds - s.events)
-            if not events:
-                break
-            folds = 0
-            for e in events:
-                if e["folds"]:
-                    e["do_eval"] = \
-                        (s.events + folds) % cfg.eval_every_rounds == 0
-                    folds += 1
-            # Event tensors (padded to K) + host-sampled batch indices
-            # in arrival order — the same rng stream as `step`.
-            n = len(events)
-            tensors = {
-                "l": np.zeros(K, dtype=np.int64),
-                "idx": np.zeros((K, k, need), dtype=np.int64),
-                "lam": np.zeros((K, k), dtype=np.float32),
-                "rhos": np.zeros((K, B), dtype=np.float32),
-                "keep": np.ones(K, dtype=np.float32),
-                "slot": np.zeros(K, dtype=np.int64),
-                "flush": np.zeros(K, dtype=bool),
-                "do_eval": np.zeros(K, dtype=bool),
-                "valid": np.arange(K) < n,
-            }
-            for i, e in enumerate(events):
-                sl = eng.orbit_slice(e["l"])
-                tensors["idx"][i] = eng.sample_indices(
-                    list(range(sl.start, sl.stop)), e["t"])
-                tensors["l"][i] = e["l"]
-                tensors["lam"][i] = e["lam"]
-                tensors["rhos"][i] = e["rhos"]
-                tensors["keep"][i] = e["keep"]
-                tensors["slot"][i] = e["slot"]
-                tensors["flush"][i] = e["flush"]
-                tensors["do_eval"][i] = e["do_eval"]
+            with obs.span("sim.plan"):
+                events = self.plan_events(eng, st, K,
+                                          cfg.max_rounds - s.events)
+                if not events:
+                    break
+                folds = 0
+                for e in events:
+                    if e["folds"]:
+                        e["do_eval"] = \
+                            (s.events + folds) % cfg.eval_every_rounds == 0
+                        folds += 1
+                # Event tensors (padded to K) + host-sampled batch indices
+                # in arrival order — the same rng stream as `step`.
+                n = len(events)
+                tensors = {
+                    "l": np.zeros(K, dtype=np.int64),
+                    "idx": np.zeros((K, k, need), dtype=np.int64),
+                    "lam": np.zeros((K, k), dtype=np.float32),
+                    "rhos": np.zeros((K, B), dtype=np.float32),
+                    "keep": np.ones(K, dtype=np.float32),
+                    "slot": np.zeros(K, dtype=np.int64),
+                    "flush": np.zeros(K, dtype=bool),
+                    "do_eval": np.zeros(K, dtype=bool),
+                    "valid": np.arange(K) < n,
+                }
+                for i, e in enumerate(events):
+                    sl = eng.orbit_slice(e["l"])
+                    tensors["idx"][i] = eng.sample_indices(
+                        list(range(sl.start, sl.stop)), e["t"])
+                    tensors["l"][i] = e["l"]
+                    tensors["lam"][i] = e["lam"]
+                    tensors["rhos"][i] = e["rhos"]
+                    tensors["keep"][i] = e["keep"]
+                    tensors["slot"][i] = e["slot"]
+                    tensors["flush"][i] = e["flush"]
+                    tensors["do_eval"][i] = e["do_eval"]
             s.params, bases, buf, accs = ex.cycle_block(
                 s.params, bases, buf, tensors, self.sat_axis_tensors)
             for i, e in enumerate(events):

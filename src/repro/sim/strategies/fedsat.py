@@ -16,6 +16,7 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.treeops import tree_add, tree_scale
 from repro.sim.strategies.base import RunState, Strategy, register_strategy
 
@@ -92,17 +93,20 @@ class FedSat(Strategy):
             s.params, bases = loaded["params"], loaded["bases"]
         while (s.events < cfg.max_rounds and s.t <= eng.horizon_s
                and s.acc < cfg.target_accuracy):
-            plan = self._plan_tick(eng, s.t)
-            if plan is None:
-                s.t += cfg.time_step_s
-                continue
-            visited, advance = plan
-            clients = [c for l in visited
-                       for c in range(l * k, (l + 1) * k)]
-            idx = eng.sample_indices(clients, s.t)
-            sizes = eng.sizes.reshape(cfg.num_orbits, k)[visited]
-            lam_rows = sizes / sizes.sum(axis=1, keepdims=True)
-            rhos = sizes.sum(axis=1) / total
+            with obs.span("sim.plan"):
+                plan = self._plan_tick(eng, s.t)
+                while plan is None:     # the next tick that visits an orbit
+                    s.t += cfg.time_step_s
+                    if s.t > eng.horizon_s:
+                        return
+                    plan = self._plan_tick(eng, s.t)
+                visited, advance = plan
+                clients = [c for l in visited
+                           for c in range(l * k, (l + 1) * k)]
+                idx = eng.sample_indices(clients, s.t)
+                sizes = eng.sizes.reshape(cfg.num_orbits, k)[visited]
+                lam_rows = sizes / sizes.sum(axis=1, keepdims=True)
+                rhos = sizes.sum(axis=1) / total
             s.params, bases = ex.fedsat_event(
                 s.params, bases, np.asarray(visited), idx, lam_rows,
                 rhos)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.treeops import tree_add, tree_sub
 from repro.core.weights import staleness_discount
 from repro.sim.strategies.base import RunState, Strategy, register_strategy
@@ -97,13 +98,15 @@ class FedSpace(Strategy):
             tag = int(meta["tag"])
         while (s.events < cfg.max_rounds and s.t <= eng.horizon_s
                and s.acc < cfg.target_accuracy):
-            vis = eng.vis_at(s.t).any(axis=0)
-            new_sats = np.nonzero(vis & ~last_seen)[0]
-            last_seen = vis
-            if eng.fault_plane is not None and len(new_sats):
-                new_sats = new_sats[eng.upload_survives(new_sats, s.t)]
+            with obs.span("sim.plan"):
+                vis = eng.vis_at(s.t).any(axis=0)
+                new_sats = np.nonzero(vis & ~last_seen)[0]
+                last_seen = vis
+                if eng.fault_plane is not None and len(new_sats):
+                    new_sats = new_sats[eng.upload_survives(new_sats, s.t)]
+                if len(new_sats):
+                    idx = eng.sample_indices(new_sats.tolist(), s.t)
             if len(new_sats):
-                idx = eng.sample_indices(new_sats.tolist(), s.t)
                 deltas, bases = ex.fedspace_train(
                     s.params, bases, new_sats, idx)
                 buffer.append((deltas, new_sats, base_tag[new_sats]))

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.data.loader import FederatedData
 
 
@@ -49,12 +50,25 @@ class LocalTrainer:
         # executor, which embeds it (vmapped) inside its own donated
         # megastep instead of dispatching `_train_many` per round.
         self.multi_step = multi_step
+
+        def train_many(stacked, images, labels):
+            with obs.scope("train"):
+                return jax.vmap(multi_step)(stacked, images, labels)
+
+        def accuracy(params, images, labels):
+            with obs.scope("eval"):
+                return model.accuracy(params, images, labels)
+
+        def accuracy_chunks(params, xs, ys):
+            with obs.scope("eval"):
+                return jax.lax.map(
+                    lambda xy: model.accuracy(params, xy[0], xy[1]),
+                    (xs, ys))
+
         self._train_one = jax.jit(multi_step)
-        self._train_many = jax.jit(jax.vmap(multi_step))
-        self._eval = jax.jit(model.accuracy)
-        self._eval_chunks = jax.jit(
-            lambda params, xs, ys: jax.lax.map(
-                lambda xy: model.accuracy(params, xy[0], xy[1]), (xs, ys)))
+        self._train_many = jax.jit(train_many)
+        self._eval = jax.jit(accuracy)
+        self._eval_chunks = jax.jit(accuracy_chunks)
 
     def init(self, seed: int = 0):
         return self.model.init(jax.random.key(seed))
@@ -159,14 +173,14 @@ class LocalTrainer:
         n_full, rem = divmod(n, batch)
         means = []
         if n_full:
-            xs = jnp.asarray(images[:n_full * batch]).reshape(
+            xs = obs.upload(images[:n_full * batch]).reshape(
                 n_full, batch, *images.shape[1:])
-            ys = jnp.asarray(labels[:n_full * batch]).reshape(n_full, batch)
+            ys = obs.upload(labels[:n_full * batch]).reshape(n_full, batch)
             means.append(self._eval_chunks(params, xs, ys))
         if rem:
-            means.append(self._eval(params, jnp.asarray(images[-rem:]),
-                                    jnp.asarray(labels[-rem:]))[None])
-        means = np.asarray(jnp.concatenate(means))       # ONE transfer
+            means.append(self._eval(params, obs.upload(images[-rem:]),
+                                    obs.upload(labels[-rem:]))[None])
+        means = obs.fetch(jnp.concatenate(means))        # ONE transfer
         lens = [batch] * n_full + ([rem] if rem else [])
         return sum(float(m) * l for m, l in zip(means, lens)) / n
 

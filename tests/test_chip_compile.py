@@ -14,6 +14,7 @@ workers that each collect this file must see the same tests. Keep every
 such compile in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.fedagg import MAX_BLOCK_P, fedagg, pick_block_p
 
 P_CNN = 1_663_370          # paper CNN parameter count
@@ -56,6 +58,29 @@ def test_fedagg_compiles_for_v5e(one_chip, s):
     compiled = jax.jit(
         lambda a, b: fedagg(a, b, interpret=False)).lower(x, w).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fold_in_scope_keeps_kernel_name(one_chip, monkeypatch):
+    """The simulator's fold inside its ``fold`` scope, as the executor
+    runs it at the paper's S=40: the custom call keeps the name that
+    the benchmark's ``fold_roofline`` matches (``^%fedagg_op(\\.\\d+)?
+    = ``) and carries the scope in its metadata."""
+    # The rehearsal's backend is the CPU: steer the kernel's interpret
+    # switch to what the chip takes.
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    tree = {"w": jax.ShapeDtypeStruct((40, P_CNN), jnp.float32,
+                                      sharding=one_chip)}
+    w = jax.ShapeDtypeStruct((40,), jnp.float32, sharding=one_chip)
+
+    def fold(t, w):
+        with jax.named_scope("fold"):
+            return ops.fold_stacked_tree(t, w, use_pallas=True)
+
+    text = jax.jit(fold).lower(tree, w).compile().as_text()
+    call = re.search(r"^\s*%fedagg_op(\.\d+)? = .*custom-call\(.*$", text,
+                     re.M)
+    assert call is not None and "tpu_custom_call" in call.group(0)
+    assert re.search(r'op_name="[^"]*fold/', call.group(0))
 
 
 def test_block_p_follows_replica_count():
