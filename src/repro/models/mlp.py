@@ -40,6 +40,12 @@ class MLP:
         gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
         return jnp.mean(logz - gold)
 
+    def loss_many(self, p: dict, images: jax.Array, labels: jax.Array):
+        """R replicas' losses, ``p`` stacked (R, ...): ``(sum, (R,))``,
+        as ``CNN.loss_many``; here the replica axis is vmap's."""
+        per = jax.vmap(self.loss)(p, images, labels)
+        return jnp.sum(per), per
+
     def accuracy(self, p: dict, images: jax.Array, labels: jax.Array):
         return jnp.mean(
             (jnp.argmax(self.forward(p, images), -1) == labels).astype(

@@ -60,7 +60,7 @@ Architecture (see also ``repro.core.strategies``):
 ``SimConfig.clients`` grammar (the virtual-client plane,
 ``repro.clients.plane``) — every training point asks the plane for the
 ``(C, local_steps * batch)`` per-satellite sample-index tables, which
-feed the existing gather -> vmapped-SGD path and the fused executor's
+feed the existing gather -> replica-stacked SGD path and the fused executor's
 schedule tensors unchanged::
 
     static                   # default: one static shard per satellite,
@@ -1077,7 +1077,7 @@ class RoundEngine:
         return self.client_plane.sample_indices(sats, t_s)
 
     def train_all(self, params: Any, t_s: float = 0.0):
-        """One local-SGD burst on every satellite (vmapped); returns the
+        """One replica-stacked local-SGD burst on every satellite; returns the
         stacked per-satellite params."""
         stacked = self.trainer.stack([params] * self.n_sats)
         sel = self.sample_indices(np.arange(self.n_sats), t_s)

@@ -219,11 +219,14 @@ class FusedExecutor:
     def _train(self, data: tuple, base: Any, idx: jax.Array, n_rep: int,
                n_steps: int) -> Any:
         """The megastep's train half: device gather of the sampled
-        mini-batch indices + one vmapped SGD burst over ``n_rep``
-        replicas broadcast from ``base`` inside jit."""
+        mini-batch indices + one SGD burst over ``n_rep`` replicas
+        broadcast from ``base`` inside jit
+        (``LocalTrainer.multi_step_many``: for the CNN the replica axis
+        lives in the activations' channels, for the MLP in vmap's
+        leading axis)."""
         with obs.scope("train"):
             x, y = self._batches(data, idx, n_rep, n_steps)
-            trained, _ = jax.vmap(self.trainer.multi_step)(
+            trained, _ = self.trainer.multi_step_many(
                 tree_broadcast(base, n_rep), x, y)
         return trained
 
@@ -564,7 +567,7 @@ class FusedExecutor:
                      idx: np.ndarray, lam_rows: np.ndarray,
                      rhos: np.ndarray):
         """One fused fedsat tick: train every member of every visited
-        orbit from its orbit's base in a single vmapped burst, then the
+        orbit from its orbit's base in a single SGD burst, then the
         method's sequential per-orbit async folds — one dispatch, no
         host tree-stacking. Returns ``(params, bases)`` on device."""
         V = len(visited)
@@ -588,7 +591,7 @@ class FusedExecutor:
                 rep = jax.tree.map(
                     lambda b: jnp.repeat(b, k, axis=0), base_rows)
                 x, y = self._batches(data, idx, Vp * k, n_steps)
-                trained, _ = jax.vmap(self.trainer.multi_step)(rep, x, y)
+                trained, _ = self.trainer.multi_step_many(rep, x, y)
 
             def orbit_fold(carry, j):
                 g, bases = carry
@@ -640,7 +643,7 @@ class FusedExecutor:
             rows = jax.tree.map(lambda b: b[sats], bases)
             with obs.scope("train"):
                 x, y = self._batches(data, idx, Np, n_steps)
-                trained, _ = jax.vmap(self.trainer.multi_step)(rows, x, y)
+                trained, _ = self.trainer.multi_step_many(rows, x, y)
             deltas = jax.tree.map(lambda t, r: t - r, trained, rows)
             bases = jax.tree.map(
                 lambda b, p: b.at[sats].set(

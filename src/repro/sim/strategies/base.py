@@ -243,7 +243,7 @@ class CycleStrategy(Strategy):
     mega shells — where contact graphs are windowed under
     ``SimConfig.isl_grid_max_bytes`` — are exact against the
     whole-horizon oracle, window boundaries included. ``step`` pops the earliest inflight
-    arrival, materializes the training it priced (one vmapped burst),
+    arrival, materializes the training it priced (one SGD burst),
     hands the orbit model to the subclass's :meth:`fold` (immediate
     async fold vs buffer-then-flush), and relaunches the orbit's next
     cycle from the new global — a pure event loop, no wall of

@@ -1,7 +1,7 @@
 """FedSat (Razmi et al., async, ideal NP GS): per-orbit periodic visits;
 the PS folds each orbit's fresh average in as it arrives.
 
-All orbits visited in one tick train as a single vmapped dispatch (one
+All orbits visited in one tick train as a single SGD burst (one
 batched mini-batch gather across every participating satellite); the
 per-orbit async folds stay sequential, as the method requires. The tick
 schedule (visited orbits, gateway delays) is param-independent — the

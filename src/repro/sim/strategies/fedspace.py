@@ -50,7 +50,7 @@ class FedSpace(Strategy):
             # retries at its next rising edge. No-loss ticks untouched.
             new_sats = new_sats[eng.upload_survives(new_sats, s.t)]
         if len(new_sats):
-            # every fresh pass in this tick trains in ONE vmapped burst
+            # every fresh pass in this tick trains in ONE SGD burst
             stacked = eng.trainer.stack(
                 [sc["sat_base"][int(x)] for x in new_sats])
             sel = eng.sample_indices(new_sats.tolist(), s.t)

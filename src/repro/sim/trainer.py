@@ -1,11 +1,12 @@
 """Local training executor for the timeline simulator.
 
 Satellites all train the same small model (the paper's CNN or MLP), so a
-round's local training is vmapped across participating satellites: one
-jitted dispatch trains every replica on its own mini-batch stream, and
-the mini-batch streams themselves come from one vectorized index gather
-across all participating clients (``sample_client_batches``) rather
-than a per-client sampling loop.
+round's local training is one replica-stacked SGD burst
+(``LocalTrainer.multi_step_many``): one jitted dispatch trains every
+replica on its own mini-batch stream, and the mini-batch streams
+themselves come from one vectorized index gather across all
+participating clients (``sample_client_batches``) rather than a
+per-client sampling loop.
 
 The index-sampling half (``sample_client_indices``) is split out so the
 fused executor (``repro.sim.executor``) can draw the *same* rng stream
@@ -25,7 +26,16 @@ from repro.data.loader import FederatedData
 
 
 class LocalTrainer:
-    """Wraps a CNN/MLP model with jitted (vmapped) local-SGD execution."""
+    """Wraps a CNN/MLP model with jitted, replica-stacked local SGD.
+
+    ``multi_step_many`` is every burst's one formulation: the fused
+    executor's programs, ``_train_many`` and ``_train_one`` (as R=1).
+    Each SGD step is one program over all R replicas, differentiating the
+    model's ``loss_many`` (the sum of the replicas' losses). Where the
+    replica axis lives is the model's choice: the CNN carries it in the
+    channel (lane) axis of its activations, the MLP vmaps its ``loss``.
+    ``multi_step`` is the one-replica burst the stacked one must match.
+    """
 
     def __init__(self, model: Any, learning_rate: float = 0.01,
                  batch_size: int = 32):
@@ -33,12 +43,14 @@ class LocalTrainer:
         self.lr = learning_rate
         self.batch_size = batch_size
 
+        def descend(params, grads):
+            return jax.tree.map(lambda p, g: p - learning_rate * g,
+                                params, grads)
+
         def sgd_step(params, images, labels):
             loss, grads = jax.value_and_grad(model.loss)(
                 params, images, labels)
-            new = jax.tree.map(lambda p, g: p - learning_rate * g,
-                               params, grads)
-            return new, loss
+            return descend(params, grads), loss
 
         def multi_step(params, images_steps, labels_steps):
             """images_steps: (n_steps, bs, ...) for ONE satellite."""
@@ -46,14 +58,37 @@ class LocalTrainer:
                 return sgd_step(p, xy[0], xy[1])
             return jax.lax.scan(body, params, (images_steps, labels_steps))
 
-        # The un-jitted per-satellite SGD burst is shared with the fused
-        # executor, which embeds it (vmapped) inside its own donated
-        # megastep instead of dispatching `_train_many` per round.
+        def stacked_step(stacked, images, labels):
+            (_, losses), grads = jax.value_and_grad(
+                model.loss_many, has_aux=True)(stacked, images, labels)
+            return descend(stacked, grads), losses
+
+        def multi_step_many(stacked, images, labels):
+            """``jax.vmap(multi_step)``'s arguments and results: params
+            stacked (R, ...), images (R, n_steps, bs, ...), labels
+            (R, n_steps, bs) -> (params (R, ...), losses (R, n_steps))."""
+            def body(p, xy):
+                return stacked_step(p, xy[0], xy[1])
+            new, losses = jax.lax.scan(
+                body, stacked,
+                (jnp.swapaxes(images, 0, 1), jnp.swapaxes(labels, 0, 1)))
+            return new, losses.T
+
+        # The un-jitted replica-stacked SGD burst is shared with the fused
+        # executor, which embeds it inside its own donated megastep
+        # instead of dispatching `_train_many` per round.
         self.multi_step = multi_step
+        self.multi_step_many = multi_step_many
+
+        def train_one(params, images, labels):
+            new, losses = multi_step_many(
+                jax.tree.map(lambda p: p[None], params), images[None],
+                labels[None])
+            return jax.tree.map(lambda p: p[0], new), losses[0]
 
         def train_many(stacked, images, labels):
             with obs.scope("train"):
-                return jax.vmap(multi_step)(stacked, images, labels)
+                return multi_step_many(stacked, images, labels)
 
         def accuracy(params, images, labels):
             with obs.scope("eval"):
@@ -65,7 +100,7 @@ class LocalTrainer:
                     lambda xy: model.accuracy(params, xy[0], xy[1]),
                     (xs, ys))
 
-        self._train_one = jax.jit(multi_step)
+        self._train_one = jax.jit(train_one)
         self._train_many = jax.jit(train_many)
         self._eval = jax.jit(accuracy)
         self._eval_chunks = jax.jit(accuracy_chunks)

@@ -6,7 +6,9 @@ refuse (VMEM overflow, HBM overflow). These tests compile the main
 path's kernel, the Pallas ``fedagg`` fold, at the paper CNN's width and
 at every replica count the cells use: S=40 is the paper 5x8 shell, 200
 the per-chip shard of an 800-satellite constellation on four chips, 800
-that constellation on one.
+that constellation on one. The paper CNN's replica-stacked SGD burst is
+compiled at the benchmark cells' replica counts, for its layout and its
+temporaries.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and pytest
@@ -22,8 +24,11 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.paper_cnn import CONFIG as CNN_CONFIG
 from repro.kernels import ops
 from repro.kernels.fedagg import MAX_BLOCK_P, fedagg, pick_block_p
+from repro.models import CNN
+from repro.sim.trainer import LocalTrainer
 
 P_CNN = 1_663_370          # paper CNN parameter count
 
@@ -81,6 +86,31 @@ def test_fold_in_scope_keeps_kernel_name(one_chip, monkeypatch):
                      re.M)
     assert call is not None and "tpu_custom_call" in call.group(0)
     assert re.search(r'op_name="[^"]*fold/', call.group(0))
+
+
+@pytest.mark.parametrize("s", [40, 32, 196, 200])
+def test_cnn_burst_has_no_vmap_layout(one_chip, s):
+    """The paper CNN's train burst, batch 32, 2 steps, at S=40 (fedhap's
+    paper-5x8 round), 32 (FedSat's padded tick) and 196 and 200 (the
+    per-chip shards of 784 and 800 satellites on four chips). With the
+    replica axis in the channels, conv1's activations are (32, 28, 28,
+    S*32): no rank-5 ``f32[S,32,28,28,32]`` buffer, the layout
+    ``jax.vmap(multi_step)`` gives them, with 32 channels padded to 128
+    lanes. The temporaries stay under 20 MB per replica: about 13 MB at
+    S=40 and 15 MB at S=196, against 42 MB and 22 MB under vmap. XLA's
+    grouped convs still make 5-D views of their own (conv2's input
+    gradient, conv1's weight gradient); this guards neither."""
+    model = CNN(CNN_CONFIG)
+    tr = LocalTrainer(model)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    stacked = jax.tree.map(lambda p: jax.ShapeDtypeStruct(
+        (s,) + p.shape, p.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((s, 2, 32, 28, 28), jnp.float32,
+                             sharding=one_chip)
+    y = jax.ShapeDtypeStruct((s, 2, 32), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(tr.multi_step_many).lower(stacked, x, y).compile()
+    assert f"f32[{s},32,28,28,32]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6 * s
 
 
 def test_block_p_follows_replica_count():
