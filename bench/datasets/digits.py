@@ -1,11 +1,19 @@
-"""The reference's own copy of the synthetic 28x28 ``digits`` data set.
+"""The synthetic 28x28 ``digits`` data set, its eval/train split and
+the paper's partition of it over the satellites: the reference's copy.
 
 A stand-in for MNIST with the same shapes and ten classes: digits drawn
 from stroke skeletons with random rotation, scale, shift, stroke
 thickness and pixel noise. The plain reference regenerates the data from
 the seed with this copy, so it takes no array that the simulator made.
-A copy of ``repro.data.digits`` as it stood when the benchmark was
-written; it has to produce the same arrays for the same seed.
+``make_digits_dataset`` and what it calls are a copy of
+``repro.data.digits`` as it stood when the benchmark was written; it has
+to produce the same arrays for the same seed.
+
+The split follows the simulator: the first ``eval_samples`` samples are
+the eval set, the rest the training set. ``satellite_sizes`` and
+``in_partition`` restate the configuration's partition of the training
+set (IID, or the paper's non-IID split by orbit), so that the families
+can check the plan's draws against it.
 """
 from __future__ import annotations
 
@@ -116,3 +124,66 @@ def make_digits_dataset(
         sl = slice(i, min(i + chunk, num_samples))
         images[sl] = _render_batch(labels[sl], rng, noise=noise)
     return images, labels
+
+
+# The paper's non-IID split (FedHAP, Sec. IV-A): the first ceil(0.6 L)
+# of L orbits hold classes 0-5, the others classes 6-9.
+CLASS_GROUPS = ((0, 1, 2, 3, 4, 5), (6, 7, 8, 9))
+NUM_CLASSES = 10
+
+
+def make(sim: dict, seed: int) -> tuple:
+    """``(train_x, train_y, eval_x, eval_y)`` of the configuration at
+    ``seed``."""
+    images, labels = make_digits_dataset(int(sim["num_samples"]), seed)
+    n_eval = int(sim["eval_samples"])
+    return images[n_eval:], labels[n_eval:], images[:n_eval], labels[:n_eval]
+
+
+def satellite_sizes(sim: dict, train_y: np.ndarray) -> np.ndarray:
+    """Training samples each satellite holds, in satellite order
+    (orbit-major): the configuration's split deals each group's samples
+    over its satellites as evenly as they go, the larger parts first."""
+    orbits, per_orbit = int(sim["num_orbits"]), int(sim["sats_per_orbit"])
+    n_sats = orbits * per_orbit
+    if sim["iid"]:
+        return _dealt(len(train_y), n_sats)
+    group_a = _group_a(orbits)
+    sizes = np.empty(n_sats, np.int64)
+    for in_a, classes in ((True, CLASS_GROUPS[0]), (False, CLASS_GROUPS[1])):
+        sats = np.flatnonzero(np.repeat(group_a == in_a, per_orbit))
+        if len(sats):
+            n = int(np.isin(train_y, classes).sum())
+            sizes[sats] = _dealt(n, len(sats))
+    return sizes
+
+
+def in_partition(sim: dict, train_y: np.ndarray, sats: np.ndarray,
+                 rows: np.ndarray) -> np.ndarray:
+    """Whether training row ``rows[r, j]`` lies in satellite
+    ``sats[r]``'s partition: whether its label is among the classes the
+    satellite holds. ``rows`` are inside the training set."""
+    return _allowed_classes(sim)[np.asarray(sats)[:, None], train_y[rows]]
+
+
+def _allowed_classes(sim: dict) -> np.ndarray:
+    """``(satellites, classes)``: which labels each satellite's
+    partition holds."""
+    orbits, per_orbit = int(sim["num_orbits"]), int(sim["sats_per_orbit"])
+    if sim["iid"]:
+        return np.ones((orbits * per_orbit, NUM_CLASSES), bool)
+    table = np.zeros((orbits, NUM_CLASSES), bool)
+    group_a = _group_a(orbits)
+    for g, classes in enumerate(CLASS_GROUPS):
+        table[np.ix_(group_a == (g == 0), classes)] = True
+    return np.repeat(table, per_orbit, axis=0)
+
+
+def _group_a(orbits: int) -> np.ndarray:
+    return np.arange(orbits) < max(1, int(np.ceil(0.6 * orbits)))
+
+
+def _dealt(n: int, parts: int) -> np.ndarray:
+    """Sizes of ``n`` items dealt into ``parts`` parts as evenly as they
+    go, the larger parts first."""
+    return n // parts + (np.arange(parts) < n % parts)

@@ -8,6 +8,7 @@ every tick, on the host path.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 import plancheck
@@ -70,8 +71,8 @@ def replay(ref, state: dict, feed: dict) -> list:
                                      feed["idx"][j * k:(j + 1) * k],
                                      feed["lam"][j])
         rho = float(feed["rhos"][j])
-        state["g"] = {n: (1.0 - rho) * state["g"][n] + rho * orbit_model[n]
-                      for n in state["g"]}
+        state["g"] = jax.tree.map(lambda g, o: (1.0 - rho) * g + rho * o,
+                                  state["g"], orbit_model)
         bases[int(orbit)] = state["g"]
         state["updates"] += 1
     return [(state["updates"], ref.accuracy(state["g"]))]

@@ -12,28 +12,29 @@ import numpy as np
 def rows(ref, sats: np.ndarray, idx: np.ndarray, seen: dict) -> int:
     """``idx[r]`` are the rows satellite ``sats[r]`` trains on: each
     replica draws ``local_steps * batch_size`` rows of the training set
-    whose labels its partition holds, no row is drawn by two satellites
-    over an episode (``seen`` carries the owners), and no satellite
-    draws more distinct rows than its partition holds."""
+    that lie in its partition (as the configuration's dataset says), no
+    row is drawn by two satellites over an episode (``seen`` carries the
+    owners), and no satellite draws more distinct rows than its
+    partition holds."""
     sats, idx = np.asarray(sats), np.asarray(idx)
-    allowed = ref.allowed_classes()
-    n = len(ref.train_labels)
+    sizes = ref.satellite_sizes()
+    n = ref.n_train
     if (idx.ndim != 2 or idx.shape != (len(sats), ref.steps * ref.bs)
-            or sats.min() < 0 or sats.max() >= len(allowed)):
+            or sats.min() < 0 or sats.max() >= len(sizes)):
         return 1
     bad = 0
     inside = (idx >= 0) & (idx < n)
     bad += int((~inside.all(axis=1)).sum())
-    labels = ref.train_labels[np.where(inside, idx, 0)]
-    bad += int((~allowed[sats[:, None], labels].all(axis=1)).sum())
+    mine = ref.in_partition(sats, np.where(inside, idx, 0))
+    bad += int((~mine.all(axis=1)).sum())
     owner = seen.setdefault("owner", np.full(n, -1, np.int64))
     for s, r in zip(sats, idx):
         r = np.unique(r[(r >= 0) & (r < n)])
         prev = owner[r]
         bad += int(np.any((prev >= 0) & (prev != s)))
         owner[r] = s
-    held = np.bincount(owner[owner >= 0], minlength=len(allowed))
-    over = set(np.flatnonzero(held > ref.satellite_sizes()))
+    held = np.bincount(owner[owner >= 0], minlength=len(sizes))
+    over = set(np.flatnonzero(held > sizes))
     bad += len(over - seen.get("over", set()))
     seen["over"] = seen.get("over", set()) | over
     return bad

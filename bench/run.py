@@ -8,8 +8,12 @@ Everything the cell needs is found by name: the cell in
 ``BENCHMARK.json``, its configuration in ``bench/configs/<config>.json``,
 its traffic in ``bench/traffic/<traffic>.json``, the family of the
 executor call it drives in ``bench/families/<family>.py``, the limits of
-its correctness check in ``bench/limits/<cell>.json`` and each per-layer
-metric's reader in ``bench/metrics/<metric>.py``.
+its correctness check in ``bench/limits/<cell>.json``, each per-layer
+metric's reader in ``bench/metrics/<metric>.py``, and the two halves of
+the plain reference (``parts.py``): the model the configuration trains
+in ``bench/payloads/<model.kind>.py`` and its data in
+``bench/datasets/<sim.dataset>.py``. An unknown name exits before any
+chip work, naming those there are.
 
 A run:
 
@@ -40,7 +44,6 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import pathlib  # noqa: E402
@@ -54,6 +57,7 @@ sys.path.insert(0, str(BENCH))
 
 import counts  # noqa: E402
 import numpy as np  # noqa: E402
+import parts  # noqa: E402
 
 # Leaves whose first update in the reference is under this share of the
 # median leaf's are left out of the update comparisons: they move by
@@ -84,6 +88,7 @@ class Cell:
     traffic: dict
     limits: dict
     family: types.ModuleType
+    payload: types.ModuleType
     end_to_end: list
     per_layer: list
 
@@ -91,15 +96,6 @@ class Cell:
 def load_json(path: pathlib.Path) -> dict:
     with open(path) as f:
         return json.load(f)
-
-
-def load_module(path: pathlib.Path) -> types.ModuleType:
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{path.parent.name}_{path.stem}".replace("-", "_")
-        .replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def for_cell(entries: list, cell: str) -> list:
@@ -121,11 +117,15 @@ def load_cell(bench_dir: pathlib.Path, name: str) -> Cell:
     configs = {c["name"]: c for c in spec["configs"]}
     config = load_json(bench_dir.parent / configs[w["config"]]["file"])
     traffic = load_json(bench_dir / "traffic" / f"{w['traffic']}.json")
+    # The reference finds its model and data by these names: an unknown
+    # one exits here, before any chip work.
+    parts.dataset(config["sim"]["dataset"], bench_dir)
     return Cell(
         name=name, bench_dir=bench_dir, chips=int(w["chips"]), config=config, traffic=traffic,
         limits=load_json(bench_dir / "limits" / f"{name}.json"),
-        family=load_module(bench_dir / "families"
+        family=parts.load_module(bench_dir / "families"
                            / f"{traffic['family']}.py"),
+        payload=parts.payload(config["model"]["kind"], bench_dir),
         end_to_end=for_cell(spec["end_to_end"], name),
         per_layer=for_cell(spec["per_layer"], name))
 
@@ -162,7 +162,9 @@ class CompileClock:
 
 
 def to_host(tree) -> dict:
-    return {k: np.asarray(v, np.float64) for k, v in tree.items()}
+    """The leaves of a pytree on the host, by their paths."""
+    from reference import leaves
+    return {k: np.asarray(v, np.float64) for k, v in leaves(tree).items()}
 
 
 def add_work(acc: dict, w: dict) -> None:
@@ -356,7 +358,8 @@ def per_layer_metrics(cell: Cell, ctx) -> dict:
     out."""
     out = {}
     for m in cell.per_layer:
-        reader = load_module(cell.bench_dir / "metrics" / f"{m['name']}.py")
+        reader = parts.load_module(cell.bench_dir / "metrics"
+                                   / f"{m['name']}.py")
         v = reader.read(ctx)
         if v is not None:
             out[m["name"]] = {"value": v, "unit": m["unit"]}
@@ -374,7 +377,8 @@ def set_up(cell: Cell, sim: dict) -> tuple:
     eng = RoundEngine(SimConfig(**sim))
     engine_build_s = time.perf_counter() - t
     n_params = eng.trainer.model.count_params()
-    if n_params != model["params"] or n_params != counts.cnn_params(model):
+    if (n_params != model["params"]
+            or n_params != cell.payload.params(model)):
         raise RunFailed(f"the program's model has {n_params} params, the "
                         f"configuration {model['params']}")
     rec = Recorder(cell.family, eng.executor, cell.chips)

@@ -7,6 +7,7 @@ where ``add_cell`` adds a cell as data alone, as a later change would.
 from __future__ import annotations
 
 import json
+import math
 import os
 import pathlib
 import shutil
@@ -26,6 +27,15 @@ sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 # set: four replicas, small enough for the CPU.
 TINY_SIM = {"num_orbits": 2, "sats_per_orbit": 2, "num_samples": 2000,
             "eval_samples": 100, "local_steps": 1}
+
+# The paper's 2NN (``payloads/mlp.py``, the program's ``model_kind="mlp"``)
+# at its published widths, as a configuration's model group.
+MLP_MODEL = {"kind": "mlp", "input_dim": 784, "hidden": [200, 200],
+             "num_classes": 10, "params": 199_210, "dtype": "float32",
+             "matmul_precision": "default",
+             "init_scale": {"w0": 1 / math.sqrt(784),
+                            "w1": 1 / math.sqrt(200),
+                            "w2": 1 / math.sqrt(200)}}
 
 
 def load(path: pathlib.Path) -> dict:
@@ -69,14 +79,17 @@ def add_cell(bench_dir: pathlib.Path, name: str, config: dict,
 
 
 def tiny_cell(bench_dir: pathlib.Path, name: str, like: str,
-              chips: int = 1, **sim) -> None:
+              chips: int = 1, model: dict | None = None, **sim) -> None:
     """A CPU-sized copy of cell ``like``: its traffic with a 3-update
-    episode and a 6 h horizon, its limits, the 2x2 shell."""
+    episode and a 6 h horizon, its limits, the 2x2 shell; ``model``, if
+    given, in place of its configuration's model group."""
     spec = load(bench_dir.parent / "BENCHMARK.json")
     w = {c["name"]: c for c in spec["workloads"]}[like]
     cfg_file = {c["name"]: c for c in spec["configs"]}[w["config"]]["file"]
     config = load(bench_dir.parent / cfg_file)
     config["sim"].update(TINY_SIM, **sim)
+    if model is not None:
+        config["model"] = model
     config["chips"] = chips
     traffic = load(bench_dir / "traffic" / f"{w['traffic']}.json")
     traffic["sim"]["horizon_h"] = 6.0

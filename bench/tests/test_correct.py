@@ -11,21 +11,30 @@ import numpy as np
 import pytest
 
 import calibrate
+import counts
 import faults
 import run
-from conftest import make_checkout, tiny_cell
+from conftest import BENCH, MLP_MODEL, load, make_checkout, tiny_cell
 from repro.sim.engine import RoundEngine
 
 CELLS = {"tiny-r.fedhap": "paper-5x8.fedhap",
-         "tiny-t.fedsat": "paper-5x8.fedsat"}
+         "tiny-t.fedsat": "paper-5x8.fedsat",
+         "tiny-m.fedhap": "paper-5x8.fedhap"}
 SEED = 2**31 + 11
+# The 2NN, which no chip cell runs: its tiny cell shows that a payload
+# comes in as new files alone.
+MODELS = {"tiny-m.fedhap": {"model": MLP_MODEL, "model_kind": "mlp"}}
+# The check values, window work and window FLOPs that the harness read on
+# the two CNN cells at SEED before the model and the data moved into
+# payloads/ and datasets/ (the same runs as ``run_tiny``).
+PARITY = load(BENCH / "tests" / "data" / "parity.json")
 
 
 @pytest.fixture(scope="module")
 def bench(tmp_path_factory):
     b = make_checkout(tmp_path_factory.mktemp("correct"))
     for name, like in CELLS.items():
-        tiny_cell(b, name, like)
+        tiny_cell(b, name, like, **MODELS.get(name, {}))
     return b
 
 
@@ -40,6 +49,29 @@ def test_sound_run_is_correct(bench, name):
     assert res["correct"], res["checks"]
     assert list(res["checks"]) == list(run.CHECKS)
     assert res["checks"]["plan_faults"]["value"] == 0
+
+
+@pytest.mark.parametrize("name", sorted(PARITY["cells"]))
+def test_parity_with_recorded_values(bench, name, monkeypatch):
+    want = PARITY["cells"][name]
+    assert PARITY["seed"] == SEED
+    seen = {}
+    unwrap = run.Recorder.unwrap
+
+    def keep_work(self):
+        seen["work"] = dict(self.work)
+        unwrap(self)
+
+    monkeypatch.setattr(run.Recorder, "unwrap", keep_work)
+    res = run_tiny(bench, name)
+    cell = run.load_cell(bench, name)
+    work = seen["work"]
+    got = {k: v["value"] for k, v in res["checks"].items()}
+    assert got == want["checks"]
+    assert {k: work[k] for k in want["work"]} == want["work"]
+    assert counts.window_flops(cell.config["model"],
+                               run.sim_config(cell, SEED),
+                               work) == want["window_flops"]
 
 
 @pytest.mark.parametrize("fault", sorted(faults.FAULTS))

@@ -1,6 +1,7 @@
 """A new cell comes in as data: files and entries, no harness edit."""
 from __future__ import annotations
 
+import json
 import os
 import pathlib
 import shutil
@@ -8,8 +9,11 @@ import subprocess
 import sys
 import types
 
+import pytest
+
 import run
-from conftest import ROOT, add_cell, load, make_checkout
+from conftest import (BENCH, MLP_MODEL, ROOT, add_cell, load, make_checkout,
+                      tiny_cell)
 
 METRIC = '''"""A metric of a later change: the engine build in milliseconds."""
 
@@ -57,12 +61,71 @@ def test_new_cell_is_listed_and_loaded_from_its_files(tmp_path):
     assert "build_ms" not in [m["name"] for m in old.per_layer]
 
 
-def _run(cwd: pathlib.Path, env_extra: dict) -> subprocess.CompletedProcess:
+def _run(cwd: pathlib.Path, env_extra: dict,
+         workload: str = "paper-5x8.fedhap") -> subprocess.CompletedProcess:
     env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra)
     return subprocess.run(
-        [sys.executable, "bench/run.py", "--workload", "paper-5x8.fedhap",
+        [sys.executable, "bench/run.py", "--workload", workload,
          "--seed", "3", "--seconds", "1", "--trace", "0"],
         cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+
+
+# Drives a run of a cell in the checkout it is started in, with the
+# checkout's own harness, skipping only the look for a chip.
+ON_CPU = """
+import json, sys
+sys.path[:0] = ["bench", sys.argv[2]]
+import jax
+import run
+cell = run.load_cell(run.BENCH, sys.argv[1])
+res = run.run_cell(cell, 2**31 + 5, 0.01, False, jax.devices()[:1])
+print(json.dumps({"correct": res["correct"], "checks": res["checks"],
+                  "payload": cell.payload.__file__}))
+"""
+
+
+def test_new_payload_is_found_by_name_and_run(tmp_path):
+    """A model that only the checkout has: its payload file and its
+    configuration are new files, and the checkout's harness loads the
+    payload by the configuration's ``model.kind``, in the runner, the
+    reference and the counts, and runs the cell correct."""
+    bench = make_checkout(tmp_path)
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    (bench / "payloads" / "two_nn.py").write_text(
+        (BENCH / "payloads" / "mlp.py").read_text())
+    tiny_cell(bench, "tiny-n.fedhap", "paper-5x8.fedhap",
+              model={**MLP_MODEL, "kind": "two_nn"}, model_kind="mlp")
+    assert all(p.read_bytes() == b for p, b in before.items())
+    assert not (BENCH / "payloads" / "two_nn.py").exists()
+    proc = subprocess.run(
+        [sys.executable, "-c", ON_CPU, "tiny-n.fedhap", str(ROOT / "src")],
+        cwd=bench.parent, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    res = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert res["correct"], res["checks"]
+    assert pathlib.Path(res["payload"]) == bench / "payloads" / "two_nn.py"
+
+
+@pytest.mark.parametrize("part,have", [("payload", "'cnn', 'mlp'"),
+                                       ("dataset", "'digits'")])
+def test_unknown_payload_or_dataset_exits_naming_those_there_are(
+        tmp_path, part, have):
+    bench = make_checkout(tmp_path)
+    config = load(bench / "configs" / "paper-5x8.json")
+    if part == "payload":
+        config["model"]["kind"] = "nosuch"
+    else:
+        config["sim"]["dataset"] = "nosuch"
+    add_cell(bench, "odd-5x8.fedhap", config,
+             load(bench / "traffic" / "fedhap-16r.json"),
+             load(bench / "limits" / "paper-5x8.fedhap.json"))
+    proc = _run(bench.parent, {}, "odd-5x8.fedhap")
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert f"unknown {part} 'nosuch'" in proc.stderr
+    assert have in proc.stderr
+    assert "no TPU" not in proc.stderr
 
 
 def test_no_tpu_exits_nonzero_and_prints_nothing():

@@ -9,6 +9,7 @@ import types
 import jax
 import pytest
 
+import parts
 import run
 import tracereduce as tr
 from conftest import make_checkout, tiny_cell
@@ -18,7 +19,7 @@ MS = 1_000_000                      # nanoseconds
 
 
 def reader(name: str):
-    return run.load_module(run.BENCH / "metrics" / f"{name}.py").read
+    return parts.load_module(run.BENCH / "metrics" / f"{name}.py").read
 
 
 def view() -> tr.TraceView:
